@@ -422,6 +422,8 @@ if __name__ == "__main__":
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows as JSON (the CI bench gate input)")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     rows: List[str] = []
     print("name,us_per_call,derived")
     if args.smoke:

@@ -28,7 +28,7 @@ def run(rows: List[str]) -> None:
 def _run(rows: List[str]) -> None:
     import jax
     import jax.numpy as jnp
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs import SHAPES, get_config

@@ -220,7 +220,8 @@ def ddim(rows: List[str], *, ndim: int = 2,
 
 def smoke(rows: List[str]) -> None:
     """CI smoke: tiny N through every engine + enumeration, agreement
-    asserted — guards the benchmark entry points against silent rot."""
+    asserted — guards the benchmark entry points against silent rot.  It
+    is the CPU CI gate, so the Pallas kernel runs in the interpreter."""
     n = 2_000
     subs, upds = make_uniform_workload(jax.random.PRNGKey(0), n // 2, n // 2,
                                        alpha=10.0)
@@ -263,7 +264,8 @@ def smoke(rows: List[str]) -> None:
                                       method=method)
         got = {(int(i), int(j)) for i, j in np.asarray(p) if i >= 0}
         assert got == want and int(c) == len(want), method
-    p, c = sbm_bitmatrix_kernel(subs2, upds2, max_pairs=cap_k)
+    p, c = sbm_bitmatrix_kernel(subs2, upds2, max_pairs=cap_k,
+                                interpret=True)
     got = {(int(i), int(j)) for i, j in np.asarray(p) if i >= 0}
     assert got == want and int(c) == len(want), "bitmatrix kernel"
     rows.append(f"ddim_smoke_talln{n2},0,K={len(want)}")
@@ -331,6 +333,8 @@ if __name__ == "__main__":
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows as JSON (the CI bench gate input)")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     fns = {"all": run, "enumeration": enumeration,
            "algorithm": wct_vs_algorithm, "n": wct_vs_n,
            "alpha": wct_vs_alpha, "scan": scan_impl_sweep,

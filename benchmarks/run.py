@@ -1,7 +1,8 @@
 """Benchmark aggregator — one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV (plus the roofline table from the
-dry-run artifacts if they exist).  Usage:
+dry-run artifacts if they exist).  A module that raises is reported as a
+``<name>_ERROR`` row and the run exits non-zero.  Usage:
     PYTHONPATH=src python -m benchmarks.run [--only matching,scaling,...]
 """
 from __future__ import annotations
@@ -20,7 +21,10 @@ def main() -> None:
     ap.add_argument("--only", default="all")
     args = ap.parse_args()
     selected = MODULES if args.only == "all" else tuple(args.only.split(","))
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
+    failed: List[str] = []
     rows: List[str] = []
     print("name,us_per_call,derived")
     for name in selected:
@@ -28,8 +32,9 @@ def main() -> None:
         t0 = time.time()
         try:
             mod.run(rows)
-        except Exception as e:   # keep the harness alive; report the failure
+        except Exception as e:   # report it, run the rest, exit non-zero
             rows.append(f"{name}_ERROR,0,{e}")
+            failed.append(name)
         for r in rows:
             print(r, flush=True)
         rows.clear()
@@ -48,6 +53,9 @@ def main() -> None:
                       f"dominant={r['dominant']} mfu_bound={r['achievable_mfu']:.3f}")
     except Exception as e:
         print(f"roofline_ERROR,0,{e}")
+        failed.append("roofline")
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

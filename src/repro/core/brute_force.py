@@ -39,7 +39,6 @@ def bf_count_sharded(subs: Extents, upds: Extents, mesh, axis_name: str,
                      *, block: int = 1024):
     """Paper §3.1 parallel BF: subscriptions sharded, updates replicated."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     # Pad to a shard multiple with inert [+inf, -inf] extents.
     num_shards = mesh.shape[axis_name]
@@ -51,7 +50,8 @@ def bf_count_sharded(subs: Extents, upds: Extents, mesh, axis_name: str,
         local = bf_count(Extents(s_lo, s_hi), Extents(u_lo, u_hi), block=block)
         return lax.psum(local, axis_name)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis_name), P(axis_name), P(), P()),
-                   out_specs=P(), check_vma=False)  # scan carry is shard-local
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(axis_name), P(axis_name), P(), P()),
+        out_specs=P(), check_vma=False))  # scan carry is shard-local
     return fn(s_lo, s_hi, upds.lo, upds.hi)

@@ -366,12 +366,12 @@ def bitmatrix_sharded(subs: Extents, upds: Extents, mesh, axis_name: str):
     the embarrassingly-parallel decomposition of the per-dimension passes
     (arXiv:1309.3458) — and the global K is a psum of per-shard popcounts.
     Subscription rows are padded to a shard multiple with inert
-    ``[+inf, -inf]`` sentinels (their words are all-zero); the returned
-    ``words`` array is sliced back to ``(n, ceil(m/32))``.
+    ``[+inf, -inf]`` sentinels.  ``words`` keeps that padding: it is
+    ``(round_up(n, P), ceil(m/32))``, row-sharded over ``axis_name``, and
+    its rows from n on are all zero (an uneven row sharding does not
+    exist, so the caller slices ``words[:n]`` where it gathers them).
     """
     from jax.sharding import PartitionSpec as P
-
-    from repro.compat import shard_map
 
     n, m = subs.size, upds.size
     if n == 0 or m == 0:
@@ -391,9 +391,8 @@ def bitmatrix_sharded(subs: Extents, upds: Extents, mesh, axis_name: str):
         partials = (lax.psum(v, axis_name) for v in _lane_partial_sums(pc))
         return words, combine_lane_partials(*partials)
 
-    fn = shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis_name), P(None, axis_name), P(), P()),
-        out_specs=(P(axis_name), P()))
-    words, count = fn(s_lo, s_hi, u_lo, u_hi)
-    return words[:n], count
+        out_specs=(P(axis_name), P())))
+    return fn(s_lo, s_hi, u_lo, u_hi)

@@ -170,20 +170,25 @@ def sbm_enumerate_sharded(subs: Extents, upds: Extents, mesh, axis_name: str,
     Mirrors :func:`repro.core.sweep.sbm_count_sharded`: the sorted stream is
     split into contiguous shards, global indicator cumsums run as the
     distributed two-level scan, and each shard emits the pairs whose
-    emitting upper endpoint it owns into a local buffer.  Global pair
-    offsets are the psum'd/all-gathered per-shard emission totals; the final
-    (max_pairs, 2) buffer is stitched from the per-shard buffers by those
-    offsets.  The rank→id tables are psum-combined (O(n+m) comm — the pair
-    payload itself is the dominant output).
+    emitting upper endpoint it owns.  Global pair offsets are the
+    psum'd/all-gathered per-shard emission totals.  The output buffer is
+    sharded over ``axis_name`` in equal slot ranges: each shard emits the
+    pairs of its global range that fall in every destination's slots and
+    one ``all_to_all`` delivers them, so a chip holds O(max_pairs) pair
+    slots in flight and keeps max_pairs / P of the result.  The rank→id
+    tables are psum-combined (O(n+m) comm — the pair payload itself is the
+    dominant output).
 
-    Per-shard buffers hold ``max_pairs_per_shard`` (default ``max_pairs``)
-    pairs; a shard emitting more drops the excess but the returned count is
-    still exact.  Without x64, a global K ≥ 2³¹ pins the count at the
-    2³¹−1 sentinel and returns an all-(-1) buffer (the cross-shard stitch
-    offsets would wrap) — never silently wrong pairs.
+    The buffer has ``max_pairs`` rounded up to a positive multiple of the
+    shard count rows (an uneven row sharding does not exist); rows past
+    ``min(count, max_pairs)`` are −1.  A shard keeps at most
+    ``max_pairs_per_shard`` (default ``max_pairs``) of its pairs and drops
+    the excess, but the returned count is still exact.  Without x64, a
+    global K ≥ 2³¹ pins the count at the 2³¹−1 sentinel and returns an
+    all-(-1) buffer (the cross-shard offsets would wrap) — never silently
+    wrong pairs.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     n = subs.lo.shape[0]
     m = upds.lo.shape[0]
@@ -192,6 +197,7 @@ def sbm_enumerate_sharded(subs: Extents, upds: Extents, mesh, axis_name: str,
     cdtype = _count_dtype()
     cap = max_pairs if max_pairs_per_shard is None else max_pairs_per_shard
     num_shards = mesh.shape[axis_name]
+    per_shard = -(-max(max_pairs, 1) // num_shards)  # output slots per shard
     ep = _pad_stream(encode_endpoints(subs, upds), num_shards)
     sub_lo, sub_up, upd_lo, upd_up = _indicator_deltas(ep)
     owner = ep.owner
@@ -237,8 +243,8 @@ def sbm_enumerate_sharded(subs: Extents, upds: Extents, mesh, axis_name: str,
             # fits int32 for any realistic shard count) and saturate, so
             # the aggregate honors the same never-wrap contract as
             # _offset_cumsum.  When the aggregate does overflow, the
-            # cross-shard stitch offsets (base/incl below) would wrap and
-            # mis-route slots to the wrong shard buffers, so the overflow
+            # cross-shard offsets (base) would wrap and route pairs to the
+            # wrong output slots, so the overflow
             # flag blanks the pair buffer: callers get the 2^31-1 count
             # sentinel and an all-(-1) buffer, never silently wrong pairs.
             hi = lax.psum(local_total >> 15, axis_name)
@@ -247,7 +253,14 @@ def sbm_enumerate_sharded(subs: Extents, upds: Extents, mesh, axis_name: str,
             overflow = (hi >= 1 << 16) | (s < 0)
             k_total = jnp.where(overflow, jnp.int32((1 << 31) - 1), s)
 
-        slots = jnp.arange(cap, dtype=jnp.int32)
+        # global slot g = dest·per_shard + t is this shard's local pair
+        # g − base; row dest of the send buffer goes to shard dest
+        g = (jnp.arange(num_shards, dtype=jnp.int32)[:, None] * per_shard
+             + jnp.arange(per_shard, dtype=jnp.int32)[None, :]).reshape(-1)
+        local = g - base
+        lvalid = ((local >= 0) & (local < jnp.minimum(local_total, cap))
+                  & (g < max_pairs) & ~overflow)
+        slots = jnp.clip(local, 0).astype(jnp.int32)
         epos = jnp.searchsorted(lc, slots, side="right").astype(jnp.int32)
         epos = jnp.minimum(epos, lc.shape[0] - 1)
         r = slots - (lc[epos] - cnt[epos])
@@ -259,28 +272,18 @@ def sbm_enumerate_sharded(subs: Extents, upds: Extents, mesh, axis_name: str,
                                      0, n - 1)]
         pi = jnp.where(emitter_is_sub, o, i_of_b)
         pj = jnp.where(emitter_is_sub, j_of_a, o)
-        lvalid = slots < local_total
-        buf = jnp.where(lvalid[:, None], jnp.stack([pi, pj], axis=-1), -1)
-        return (buf, base.reshape(1).astype(cdtype),
-                local_total.reshape(1).astype(cdtype), k_total, overflow)
+        send = jnp.where(lvalid[:, None], jnp.stack([pi, pj], axis=-1), -1)
+        recv = lax.all_to_all(send.reshape(num_shards, per_shard, 2),
+                              axis_name, 0, 0)
+        # the shards' global ranges are disjoint: at most one source holds
+        # a pair for each slot, the rest send −1
+        return jnp.max(recv, axis=0), k_total
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis_name), P(axis_name), P(axis_name),
-                             P(axis_name), P(axis_name)),
-                   out_specs=(P(axis_name), P(axis_name), P(axis_name), P(),
-                              P()))
-    buf, base, local_totals, k_total, overflow = fn(sub_lo, upd_lo, owner,
-                                                    is_upper, is_sub)
-    bufs = buf.reshape(num_shards, cap, 2)
-    incl = base + local_totals                      # per-shard global ranges
-    slots = jnp.arange(max_pairs, dtype=jnp.int32)
-    p = jnp.minimum(jnp.searchsorted(incl, slots, side="right"),
-                    num_shards - 1).astype(jnp.int32)
-    r = slots - base[p]
-    valid = (slots < jnp.minimum(k_total, max_pairs)) & (r < cap) & ~overflow
-    pairs = jnp.where(valid[:, None],
-                      bufs[p, jnp.clip(r, 0, cap - 1)], -1)
-    return pairs, k_total
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(axis_name),) * 5,
+        out_specs=(P(axis_name), P())))
+    return fn(sub_lo, upd_lo, owner, is_upper, is_sub)
 
 
 # ---------------------------------------------------------------------------

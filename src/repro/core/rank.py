@@ -58,7 +58,6 @@ def rank_count_sharded(subs: Extents, upds: Extents, mesh, axis_name: str):
     interval tree); subscription queries are sharded; a final psum reduces.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     u_lo_sorted = jnp.sort(upds.lo)
     u_hi_sorted = jnp.sort(upds.hi)
@@ -75,7 +74,8 @@ def rank_count_sharded(subs: Extents, upds: Extents, mesh, axis_name: str):
         ended = jnp.searchsorted(u_hi, s_lo, side="left")
         return lax.psum(jnp.sum(started - ended), axis_name)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis_name), P(axis_name), P(), P()),
-                   out_specs=P())
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(axis_name), P(axis_name), P(), P()),
+        out_specs=P()))
     return fn(s_lo, s_hi, u_lo_sorted, u_hi_sorted)

@@ -406,18 +406,17 @@ def sbm_count_sharded(subs: Extents, upds: Extents, mesh, axis_name: str):
     carry crosses devices via the two-level scan (all_gather of partials).
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     num_shards = mesh.shape[axis_name]
     ep = _pad_stream(encode_endpoints(subs, upds), num_shards)
     sub_lo, sub_up, upd_lo, upd_up = _indicator_deltas(ep)
 
-    fn = shard_map(
+    fn = jax.jit(jax.shard_map(
         functools.partial(sbm_count_shard_body, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name), P(axis_name)),
         out_specs=P(),
-    )
+    ))
     return fn(sub_lo, sub_up, upd_lo, upd_up)
 
 

@@ -55,7 +55,8 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from repro.core import runtime as runtime_lib
-from repro.core.errors import DeadlineExceeded, OverloadError, ValidationError
+from repro.core.errors import (DDMError, DeadlineExceeded, OverloadError,
+                               ValidationError)
 from repro.core.incremental import BatchDelta
 from repro.core.service import DDMService
 
@@ -230,6 +231,7 @@ GUARDED_BY = {
         "flushes": "_lock",
         "degraded_reads": "_lock",
         "exact_reads": "_lock",
+        "broken": "_lock",
     },
     "Broker": {
         "_sessions": "_lock",
@@ -279,6 +281,9 @@ class BrokerSession:
         self.flushes = 0
         self.degraded_reads = 0
         self.exact_reads = 0
+        # the error of a failed service flush: the service's tables may
+        # hold ops its index never took, so the session serves nothing more
+        self.broken: Optional[BaseException] = None
 
     # -- producer side -----------------------------------------------------
     @property
@@ -332,6 +337,7 @@ class BrokerSession:
     def _submit(self, op: _Op) -> Ticket:
         pol = self.admission
         with self._space:
+            self._check_usable_locked()
             if len(self._queue) >= pol.max_queue:
                 if pol.backpressure == "reject":
                     self.rejected += 1
@@ -368,7 +374,11 @@ class BrokerSession:
         :class:`DeadlineExceeded`), service-refused ops fail their ticket
         and do not poison the rest of the batch.  Tickets resolve only
         after the service flush lands — a resolved register is durable in
-        the index.
+        the index.  If the service flush itself raises, every op of the
+        batch fails with that error, the error propagates, and the session
+        is broken: its service's tables may already hold ops its index does
+        not, so every later op, flush and read raises :class:`DDMError`
+        from that error.
         """
         with self._lock:
             return self._flush_locked()
@@ -379,8 +389,15 @@ class BrokerSession:
         if assert_held is not None:
             assert_held()
 
+    def _check_usable_locked(self) -> None:
+        if self.broken is not None:
+            raise DDMError(
+                f"session {self.name!r} is broken: a service flush failed "
+                f"({self.broken!r})") from self.broken
+
     def _flush_locked(self) -> BatchDelta:
         self._assert_lock_held()
+        self._check_usable_locked()
         t0 = time.perf_counter()
         now = self._clock()
         ops = list(self._queue)
@@ -403,7 +420,18 @@ class BrokerSession:
         # cleared so an empty flush can't fold a previous batch's surgery
         # stats into this record
         self._svc._index.last_batch_stats = None
-        delta = self._svc.flush()
+        try:
+            delta = self._svc.flush()
+        except Exception as exc:
+            # the service may have applied part of the batch to its tables
+            # (and handed out rids) but not to its index: fail every op of
+            # the batch and break the session rather than serve that state
+            self.broken = exc
+            self.failed += len(applied)
+            for op, _result in applied:
+                op.ticket._fail(exc)
+            self._space.notify_all()
+            raise
         dt = time.perf_counter() - t0
         self._flush_seconds.append(dt)
         self.flushes += 1
@@ -488,6 +516,7 @@ class BrokerSession:
         reads arbitrarily slow.
         """
         with self._lock:
+            self._check_usable_locked()
             if not self._degraded_locked():
                 self._flush_locked()
                 self.exact_reads += 1
@@ -638,10 +667,20 @@ class Broker:
 
     # -- flushing ----------------------------------------------------------
     def flush_all(self) -> Dict[str, BatchDelta]:
-        """One flush per session (in name order); name → delta."""
+        """One flush per session (in name order); name → delta.  A session
+        whose flush raises does not stop the others: the first error is
+        raised once every session was flushed."""
         with self._lock:
             sessions = sorted(self._sessions.items())
-        return {name: sess.flush() for name, sess in sessions}
+        deltas, first_error = {}, None
+        for name, sess in sessions:
+            try:
+                deltas[name] = sess.flush()
+            except Exception as exc:
+                first_error = first_error or exc
+        if first_error is not None:
+            raise first_error
+        return deltas
 
     def start(self) -> None:
         """Start the periodic flusher (idempotent)."""

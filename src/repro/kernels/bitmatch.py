@@ -2,21 +2,25 @@
 
 The journal version of the source paper (arXiv:1911.03456) combines
 per-dimension match bit-vectors with bitwise AND.  On TPU that maps onto a
-grid over *subscription row blocks*: each grid step holds one ``(BLOCK_N,)``
-slice of subscription extents (all d dimensions) and the full update set in
-VMEM, evaluates the d closed-interval overlap masks on the VPU, AND-reduces
-them, packs each row into ``ceil(m/32)`` ``uint32`` words (a weighted
-lane-sum — no bit loops), and popcounts the words for the per-row match
-counts.  The boolean n × m mask never exists in HBM: only the 32×-smaller
-packed words and the per-row counts leave the kernel.
+2-D grid over *subscription row blocks* × *word blocks*: each grid step
+holds one ``(BLOCK_N, d)`` slice of subscription extents and the updates
+of ``WORD_BLOCK`` packed words, evaluates the d closed-interval overlap
+masks on the VPU, AND-reduces them, and ORs them straight into packed
+words.  The updates arrive bit-major — row ``32·dim + b`` holds the
+updates ``32·w + b`` for every word ``w`` — so bit ``b`` of every word is
+one ``(BLOCK_N, WORD_BLOCK)`` compare, shift and OR: no lane reshape, no
+unsigned reduction.  Words are int32 inside the kernel (bit 31 is the
+sign bit; the bits are disjoint, so OR equals sum) and are bitcast to
+``uint32`` outside.  Per-row match counts accumulate across the word axis.
+The boolean n × m mask never exists anywhere: only the packed words and
+the per-row counts leave the kernel.
 
-VMEM budget per grid step: the ``(BLOCK_N, m)`` comparison mask dominates
-at 4·BLOCK_N·m bytes of int32 lanes, so with the ~16 MB/core budget the
-product BLOCK_N·m must stay around 10⁶ — the default ``block_n = 256``
-covers m up to ~8k updates; shrink ``block_n`` proportionally for larger
-update sets (``block_n = 32`` reaches m ≈ 65k).  The update axis is
-padded to a lane multiple (128) with inert ``[+inf, -inf]`` sentinels
-whose bits are always zero.
+VMEM per grid step: a few ``(BLOCK_N, WORD_BLOCK)`` int32 tiles —
+4·256·512 = 512 KiB each at the defaults — plus the double-buffered
+update block, 2·2·(32·d)·WORD_BLOCK·4 bytes; both are independent of n
+and m, so every size stays within the v5e scoped-VMEM default.  Padding
+rows and updates are inert ``[+inf, -inf]`` sentinels whose bits are
+always zero.
 
 The pure-jnp oracle is :func:`repro.core.ddim.bitmatrix_words`; agreement
 (words, counts, and the emitted pair set) is pinned in
@@ -25,78 +29,97 @@ The pure-jnp oracle is :func:`repro.core.ddim.bitmatrix_words`; agreement
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import ddim as ddim_lib
-from repro.core import prefix as prefix_lib
 from repro.core.intervals import Extents
+
+WORD_BLOCK = 512
 
 
 def _bitmatch_kernel(s_lo_ref, s_hi_ref, u_lo_ref, u_hi_ref,
                      words_ref, counts_ref):
-    """One grid step = one subscription row block against every update.
+    """One grid step = one subscription row block against one word block.
 
-    s_lo/s_hi: (d, BLOCK_N) f32; u_lo/u_hi: (d, M) f32 (lane-padded).
-    words_ref: (BLOCK_N, M // 32) uint32; counts_ref: (BLOCK_N, 1) int32.
+    s_lo/s_hi: (BLOCK_N, d) f32; u_lo/u_hi: (32·d, WB) f32, bit-major.
+    words_ref: (BLOCK_N, WB) int32; counts_ref: (BLOCK_N, 1) int32,
+    accumulated over the word axis of the grid.
     """
-    d = s_lo_ref.shape[0]
-    m = u_lo_ref.shape[1]
-    mask = None
-    for dd in range(d):  # static unroll — d is a compile-time constant
-        hit = (s_lo_ref[dd, :][:, None] <= u_hi_ref[dd, :][None, :]) & (
-            u_lo_ref[dd, :][None, :] <= s_hi_ref[dd, :][:, None]
-        )
-        mask = hit if mask is None else mask & hit
-    # pack in-VMEM with the canonical bit layout (m is lane-padded to a
-    # multiple of 128, so pack_bits' pad branch is statically dead)
-    assert m % 32 == 0
-    words = prefix_lib.pack_bits(mask)
+    d = s_lo_ref.shape[1]
+    words = jnp.zeros(words_ref.shape, jnp.int32)
+    hits = jnp.zeros(words_ref.shape, jnp.int32)
+    for b in range(32):  # static unroll — bit b of every word at once
+        mask = None
+        for dd in range(d):
+            r = 32 * dd + b
+            hit = (s_lo_ref[:, dd:dd + 1] <= u_hi_ref[r:r + 1, :]) & (
+                u_lo_ref[r:r + 1, :] <= s_hi_ref[:, dd:dd + 1])
+            mask = hit if mask is None else mask & hit
+        bit = mask.astype(jnp.int32)
+        words = words | jnp.left_shift(bit, b)
+        hits = hits + bit
     words_ref[...] = words
-    counts_ref[...] = jnp.sum(
-        lax.population_count(words).astype(jnp.int32), axis=-1,
-        dtype=jnp.int32, keepdims=True
-    )
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        counts_ref[...] = jnp.zeros(counts_ref.shape, jnp.int32)
+
+    counts_ref[...] += jnp.sum(hits, axis=-1, keepdims=True)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_n", "interpret")
+    jax.jit, static_argnames=("block_n", "word_block", "interpret")
 )
 def _bitmatrix_pallas_jit(s_lo, s_hi, u_lo, u_hi, *, block_n: int,
-                          interpret: bool):
-    d, n_pad = s_lo.shape
-    m_pad = u_lo.shape[1]
-    num_blocks = n_pad // block_n
-    num_words = m_pad // 32
-    ext_spec = pl.BlockSpec((d, block_n), lambda i: (0, i))
-    upd_spec = pl.BlockSpec((d, m_pad), lambda i: (0, 0))
+                          word_block: int, interpret: bool):
+    """``s_*``: (n_pad, d) rows; ``u_*``: (32·d, W_pad) bit-major words."""
+    n_pad, d = s_lo.shape
+    w_pad = u_lo.shape[1]
+    grid = (n_pad // block_n, w_pad // word_block)
+    ext_spec = pl.BlockSpec((block_n, d), lambda i, j: (i, 0))
+    upd_spec = pl.BlockSpec((32 * d, word_block), lambda i, j: (0, j))
     words, counts = pl.pallas_call(
         _bitmatch_kernel,
-        grid=(num_blocks,),
+        grid=grid,
         in_specs=[ext_spec, ext_spec, upd_spec, upd_spec],
         out_specs=[
-            pl.BlockSpec((block_n, num_words), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_n, word_block), lambda i, j: (i, j)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad, num_words), jnp.uint32),
+            jax.ShapeDtypeStruct((n_pad, w_pad), jnp.int32),
             jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(s_lo, s_hi, u_lo, u_hi)
-    return words, counts[:, 0]
+    return lax.bitcast_convert_type(words, jnp.uint32), counts[:, 0]
+
+
+def _bit_major(lo, hi, num_words: int):
+    """(d, m) update columns → (32·d, num_words): row 32·dim + b, column w
+    holds update 32·w + b (inert sentinels past m)."""
+    d = lo.shape[0]
+    lo, hi = ddim_lib._pad_axis(lo, hi, 32 * num_words)
+    def perm(x):
+        return x.reshape(d, num_words, 32).transpose(0, 2, 1).reshape(
+            32 * d, num_words)
+    return perm(lo), perm(hi)
 
 
 def bitmatrix_pallas(
     subs: Extents,
     upds: Extents,
     *,
+    interpret: bool,
     block_n: int = 256,
-    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(words, row_counts, k_total) via the blockwise VMEM pack/AND kernel.
 
@@ -107,8 +130,6 @@ def bitmatrix_pallas(
     (``repro.core.ddim._popcount_total``): exact int64 under x64,
     saturating at 2³¹−1 without — never a silent wrap.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n, m = subs.size, upds.size
     num_words = max(-(-m // 32), 1)
     if n == 0 or m == 0:
@@ -117,17 +138,20 @@ def bitmatrix_pallas(
             jnp.zeros((n,), jnp.int32),
             jnp.zeros((), ddim_lib._count_dtype()),
         )
-    s_lo, s_hi = ddim_lib._dim_rows(subs)
-    u_lo, u_hi = ddim_lib._dim_rows(upds)
-    block_n = min(block_n, max(8, n))
-    s_lo, s_hi = ddim_lib._pad_axis(s_lo, s_hi, block_n)
-    u_lo, u_hi = ddim_lib._pad_axis(u_lo, u_hi, 128)
+    # rows: a multiple of 8 sublanes; words: one block when they fit,
+    # else whole WORD_BLOCK lane tiles
+    block_n = min(block_n, -(-n // 8) * 8)
+    word_block = (num_words if num_words <= WORD_BLOCK else WORD_BLOCK)
+    w_pad = -(-num_words // word_block) * word_block
+    s_lo, s_hi = ddim_lib._pad_axis(*ddim_lib._dim_rows(subs), block_n)
+    u_lo, u_hi = _bit_major(*ddim_lib._dim_rows(upds), w_pad)
     words, counts = _bitmatrix_pallas_jit(
-        s_lo, s_hi, u_lo, u_hi, block_n=block_n, interpret=interpret
+        s_lo.T, s_hi.T, u_lo, u_hi, block_n=block_n, word_block=word_block,
+        interpret=interpret,
     )
     words = words[:n, :num_words]
     counts = counts[:n]
-    # total from the kernel's own row popcounts (n terms, lane-safe) —
+    # total from the kernel's own row counts (n terms, lane-safe) —
     # no second pass over the n x ceil(m/32) word matrix
     return words, counts, ddim_lib._lane_safe_sum(counts)
 
@@ -137,8 +161,8 @@ def sbm_bitmatrix_kernel(
     upds: Extents,
     *,
     max_pairs: int,
+    interpret: bool,
     block_n: int = 256,
-    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """d-dim (pairs, count) with the kernel-packed bit matrix as the engine.
 
@@ -153,7 +177,7 @@ def sbm_bitmatrix_kernel(
             jnp.zeros((), ddim_lib._count_dtype()),
         )
     words, _counts, k_total = bitmatrix_pallas(
-        subs, upds, block_n=block_n, interpret=interpret
+        subs, upds, interpret=interpret, block_n=block_n
     )
     return ddim_lib.pairs_from_bitmatrix(
         words, m=m, max_pairs=max_pairs, count=k_total

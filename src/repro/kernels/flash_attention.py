@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core.errors import ValidationError
 
 NEG_INF = -1.0e30  # finite mask value: keeps exp() well-defined on dead rows
@@ -166,7 +165,7 @@ def flash_attention_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(kv_index, kv_count, q, k, v, q_segments, kv_segments)

@@ -1,7 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work on CPU
-(kernel body emulated) and compile to Mosaic on TPU.
+``interpret`` is an explicit keyword on every wrapper: ``False`` compiles
+the kernel for the TPU, ``True`` runs the Pallas interpreter (the CPU test
+path).  Nothing picks it from the backend.
 """
 from __future__ import annotations
 
@@ -19,19 +20,13 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import sbm_sweep as sweep_kernels
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 # ---------------------------------------------------------------------------
 # SBM counting sweep
 # ---------------------------------------------------------------------------
 
-def sbm_count_kernel(subs: Extents, upds: Extents, *, block_size: int = 2048,
-                     interpret: Optional[bool] = None) -> jax.Array:
+def sbm_count_kernel(subs: Extents, upds: Extents, *, interpret: bool,
+                     block_size: int = 2048) -> jax.Array:
     """K via the Pallas two-pass sweep (sort on XLA, sweep on the kernel)."""
-    if interpret is None:
-        interpret = _default_interpret()
     ep = _pad_stream(encode_endpoints(subs, upds), block_size)
     deltas = jnp.stack(_indicator_deltas(ep))          # (4, total)
     _, k = sweep_kernels.sweep_count_pallas(
@@ -39,11 +34,9 @@ def sbm_count_kernel(subs: Extents, upds: Extents, *, block_size: int = 2048,
     return k
 
 
-def sbm_delta_bitmasks(subs: Extents, upds: Extents, *, block_size: int = 1024,
-                       interpret: Optional[bool] = None):
+def sbm_delta_bitmasks(subs: Extents, upds: Extents, *, interpret: bool,
+                       block_size: int = 1024):
     """Algorithm 6's (Sadd, Sdel, Uadd, Udel) as per-segment bitmask words."""
-    if interpret is None:
-        interpret = _default_interpret()
     n, m = subs.lo.shape[0], upds.lo.shape[0]
     ep = _pad_stream(encode_endpoints(subs, upds), block_size)
     up = ep.is_upper.astype(jnp.int32)
@@ -81,16 +74,15 @@ def _stitch_blocks(out_i, out_j, block_sums, k_total, *, max_pairs: int,
 
 
 def sbm_enumerate_kernel(subs: Extents, upds: Extents, *, max_pairs: int,
-                         block_size: int = 512,
-                         max_pairs_per_block: Optional[int] = None,
-                         interpret: Optional[bool] = None
+                         interpret: bool, block_size: int = 1024,
+                         max_pairs_per_block: Optional[int] = None
                          ) -> Tuple[jax.Array, jax.Array]:
     """All matching (i, j) pairs via the three-pass Pallas sweep.
 
     Pass A/B (counting kernel) size the output: per-block emission totals
     and their exclusive scan are the cross-block pair offsets.  The bitmask
     delta pass plus the Algorithm-6 monoid combine seed each block's active
-    sets, and pass C walks those VMEM bitmasks at every upper endpoint,
+    sets, and pass C walks those SMEM bitmasks at every upper endpoint,
     scattering pairs into per-block regions that are stitched by the offset
     table.  Same contract as :func:`repro.core.sbm_enumerate` (pairs padded
     with -1; count exact even past ``max_pairs``).
@@ -99,8 +91,6 @@ def sbm_enumerate_kernel(subs: Extents, upds: Extents, *, max_pairs: int,
     it is sized from the observed maximum block total (one host sync + one
     recompile per new high-water mark).
     """
-    if interpret is None:
-        interpret = _default_interpret()
     n, m = subs.lo.shape[0], upds.lo.shape[0]
     if n == 0 or m == 0:
         return jnp.full((max_pairs, 2), -1, jnp.int32), jnp.int32(0)
@@ -204,7 +194,7 @@ def flash_attention(
     num_global_blocks: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool,
 ) -> jax.Array:
     """Interest-managed flash attention (public API).
 
@@ -213,8 +203,6 @@ def flash_attention(
     token-level structure (diagonal causality, window edges, document
     boundaries via segments).
     """
-    if interpret is None:
-        interpret = _default_interpret()
     B, H, Sq, D = q.shape
     Skv = k.shape[2]
     kv_index, kv_count, _ = build_block_structure(

@@ -10,14 +10,13 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_elastic_mesh(devices: Optional[Sequence] = None, *,
@@ -35,14 +34,6 @@ def make_elastic_mesh(devices: Optional[Sequence] = None, *,
     import numpy as np
     arr = np.asarray(devices).reshape(n // model_parallel, model_parallel)
     from jax.sharding import Mesh
-    try:
-        return Mesh(arr, ("data", "model"),
-                    axis_types=(AxisType.Auto, AxisType.Auto))
-    except TypeError:  # pre-AxisType jax: meshes are implicitly Auto
-        return Mesh(arr, ("data", "model"))
+    return Mesh(arr, ("data", "model"),
+                axis_types=(AxisType.Auto, AxisType.Auto))
 
-
-def make_host_mesh(num: Optional[int] = None, axis: str = "data"):
-    """1-D mesh over host-emulated devices (tests, benchmarks)."""
-    devices = jax.devices()[:num]
-    return make_mesh((len(devices),), (axis,), axis_types=(AxisType.Auto,))

@@ -116,7 +116,7 @@ def _moe_apply_shard_map(params, x, bin_token, bin_gate, cfg, sharder,
 
     All reductions happen in bf16.
     """
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = sharder.mesh

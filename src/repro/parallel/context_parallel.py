@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
-
 NEG_INF = -1.0e30
 
 
@@ -74,7 +72,7 @@ def halo_window_attention(q, k, v, *, window: int, axis_name: str,
     if scale is None:
         scale = hd ** -0.5
     idx = lax.axis_index(axis_name)
-    p = compat.axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     num_halo = -(-window // s_l)                   # whole-chunk halos
     if num_halo >= p:
         raise ValueError(f"{window=} spans the whole ring; use ring_attention")
@@ -118,7 +116,7 @@ def ring_attention(q, k, v, *, axis_name: str,
     if scale is None:
         scale = hd ** -0.5
     idx = lax.axis_index(axis_name)
-    p = compat.axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     perm = [(i, (i + 1) % p) for i in range(p)]    # rotate right
     q5 = _split(q, kvh).astype(jnp.float32)
     q_pos = (idx * s_l + jnp.arange(s_l))[:, None]

@@ -200,7 +200,8 @@ def _sweep_pallas_pairs(subs, upds):
         return set()     # kernel grids need a nonempty endpoint stream
     return pairs_via_retry(
         lambda s, u, max_pairs: sbm_enumerate_kernel(
-            s, u, max_pairs=max_pairs, block_size=256), subs, upds)
+            s, u, max_pairs=max_pairs, interpret=True, block_size=256),
+        subs, upds)
 
 
 def _bitmatrix_pairs(subs, upds):
@@ -218,7 +219,8 @@ def _bitmatrix_pallas_pairs(subs, upds):
         return set()     # kernel grids need nonempty extent sets
     return pairs_via_retry(
         lambda s, u, max_pairs: sbm_bitmatrix_kernel(
-            s, u, max_pairs=max_pairs, block_n=128), subs, upds)
+            s, u, max_pairs=max_pairs, interpret=True, block_n=128),
+        subs, upds)
 
 
 def _incremental_pairs_impl(subs, upds, index_impl, block_target=None):

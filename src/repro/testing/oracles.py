@@ -49,17 +49,20 @@ def brute_force_pairs(subs: Extents, upds: Extents) -> PairSet:
     return brute_force_pairs_numpy(subs, upds)
 
 
-def reference_pairs(subs: Extents, upds: Extents) -> PairSet:
+def reference_pairs(subs: Extents, upds: Extents,
+                    sweep_dim: int = 0) -> PairSet:
     """THE oracle: sequential sweep cross-checked against brute force.
 
     The two references share no code path (one is a sorted endpoint scan,
     the other a broadcast comparison), so their agreement is itself part
     of the conformance substrate; disagreement raises immediately rather
-    than grading engines against a possibly-wrong answer.
+    than grading engines against a possibly-wrong answer.  ``sweep_dim``
+    picks the dimension the sequential sweep runs on (any gives the same
+    set; a selective one keeps its candidate set small).
     """
     if subs.size == 0 or upds.size == 0:
         return set()
-    want = sequential_sbm_pairs_numpy_ddim(subs, upds)
+    want = sequential_sbm_pairs_numpy_ddim(subs, upds, sweep_dim)
     bf = brute_force_pairs_numpy(subs, upds)
     if want != bf:
         raise AssertionError(

@@ -51,12 +51,12 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.parallel.compression import compressed_psum
 
-    from repro.compat import AxisType, make_mesh
-    mesh = make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
     g = jax.random.normal(jax.random.PRNGKey(0), (4, 1024)) * 0.01
     err = jnp.zeros((4, 1024))
 

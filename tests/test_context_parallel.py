@@ -12,15 +12,15 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.models.attention import dense_attention
     from repro.parallel.context_parallel import (halo_window_attention,
                                                  ring_attention, cp_specs)
 
-    from repro.compat import AxisType, make_mesh
-    mesh = make_mesh((2, 4), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     b, h, kvh, s, hd = 2, 4, 2, 256, 16
     key = jax.random.PRNGKey(0)
     q = jax.random.normal(key, (b, h, s, hd))

@@ -20,7 +20,7 @@ _SCRIPT = textwrap.dedent("""
                             rank_count_sharded, bf_count_sharded,
                             brute_force_count_numpy)
     from repro.core.prefix import shard_inclusive_cumsum
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     import numpy as np
 
@@ -55,6 +55,16 @@ _SCRIPT = textwrap.dedent("""
     got_pairs = {(int(i), int(j)) for i, j in np.asarray(pairs) if i >= 0}
     assert int(cnt) == len(want_pairs), (int(cnt), len(want_pairs))
     assert got_pairs == want_pairs
+    # the buffer stays row-sharded: max_pairs rounded up to 8 slot ranges
+    assert pairs.shape == (-(-(len(want_pairs) + 32) // 8) * 8, 2)
+    assert len(pairs.sharding.device_set) == 8
+    assert not pairs.sharding.is_fully_replicated
+    # a per-shard cap below every shard's share drops pairs, never the count
+    capped, cnt_c = sbm_enumerate_sharded(subs, upds, mesh, "p",
+                                          max_pairs=len(want_pairs),
+                                          max_pairs_per_shard=4)
+    got_c = {(int(i), int(j)) for i, j in np.asarray(capped) if i >= 0}
+    assert int(cnt_c) == len(want_pairs) and got_c < want_pairs
 
     # d-dim bit-matrix sharded over subscription rows (n not a shard
     # multiple -> inert-row padding): words and count must equal the
@@ -63,8 +73,10 @@ _SCRIPT = textwrap.dedent("""
     subs2, upds2 = make_tall_thin_workload(jax.random.PRNGKey(7), 101, 90,
                                            alpha=8.0, d=2, length=1000.0)
     words, cnt2 = bitmatrix_sharded(subs2, upds2, mesh, "p")
-    np.testing.assert_array_equal(np.asarray(words),
+    assert words.shape[0] == 104 and not words.sharding.is_fully_replicated
+    np.testing.assert_array_equal(np.asarray(words)[:101],
                                   np.asarray(bitmatrix_words(subs2, upds2)))
+    assert not np.asarray(words)[101:].any()     # inert padding rows
     from repro.core import brute_force_pairs_numpy as bf_pairs
     assert int(cnt2) == len(bf_pairs(subs2, upds2)), int(cnt2)
 

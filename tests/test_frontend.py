@@ -19,6 +19,8 @@ from repro.api import (
     AdmissionPolicy,
     Broker,
     CountResult,
+    DDMError,
+    DDMService,
     DeadlineExceeded,
     DegradePolicy,
     OverloadError,
@@ -69,6 +71,49 @@ def test_bad_op_fails_its_ticket_not_the_batch():
         bad.result(timeout=0)
     assert sess.pairs() == {(good.result(0), also_good.result(0))}
     assert sess.stats()["failed"] == 1
+
+
+def test_service_flush_failure_fails_the_batch_tickets():
+    class LostDevice(DDMService):
+        def flush(self):
+            raise RuntimeError("device lost")
+
+    broker = Broker(service_factory=LostDevice)
+    sess = broker.create_session("s", dims=1)
+    tickets = [sess.register("sub", 0.0, 1.0), sess.register("upd", 0.5, 2.0)]
+    with pytest.raises(RuntimeError, match="device lost"):
+        sess.flush()
+    for t in tickets:
+        assert t.done()
+        with pytest.raises(RuntimeError, match="device lost"):
+            t.result(timeout=0)
+    assert sess.stats()["failed"] == 2 and sess.queue_depth == 0
+
+
+def test_failed_service_flush_breaks_the_session():
+    class LostDevice(DDMService):
+        def flush(self):
+            raise RuntimeError("device lost")
+
+    kinds = [LostDevice, DDMService]      # one per create_session call
+    broker = Broker(service_factory=lambda **kw: kinds.pop(0)(**kw))
+    lost = broker.create_session("a-lost", dims=1)
+    ok = broker.create_session("b-ok", dims=1)
+    lost.register("sub", 0.0, 1.0)
+    t_ok = ok.register("sub", 0.0, 1.0)
+    # the healthy session is flushed although the first one raises
+    with pytest.raises(RuntimeError, match="device lost"):
+        broker.flush_all()
+    assert isinstance(t_ok.result(timeout=0), int)
+    # the lost session's tables hold a region its index never took: it
+    # refuses every later op, flush and read with the stored cause
+    for call in (lambda: lost.register("upd", 0.5, 2.0), lost.flush,
+                 lost.match_count, lost.pairs):
+        with pytest.raises(DDMError, match="broken") as err:
+            call()
+        assert isinstance(err.value.__cause__, RuntimeError)
+    assert lost.queue_depth == 0
+    assert ok.match_count().count == 0
 
 
 def test_move_and_unregister_through_queue():

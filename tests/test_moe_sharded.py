@@ -19,9 +19,9 @@ _SCRIPT = textwrap.dedent("""
     from repro.models.api import init_params
     from repro.parallel.sharding import Sharder, make_sharder
 
-    from repro.compat import AxisType, make_mesh
-    mesh = make_mesh((2, 4), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     base = dataclasses.replace(
         reduce_config(get_config("granite-moe-3b-a800m")),
         d_model=32, d_ff=64, num_experts=4, num_experts_per_token=2,
