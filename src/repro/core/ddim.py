@@ -32,6 +32,7 @@ and the sequential Algorithm-4 sweep extended to d dims
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional, Tuple
 
@@ -65,31 +66,39 @@ def _dim_rows(e: Extents) -> Tuple[jax.Array, jax.Array]:
 # ---------------------------------------------------------------------------
 
 def per_dimension_counts(
-    subs: Extents, upds: Extents, *, num_segments: int = 8
+    subs: Extents, upds: Extents, *, num_segments: int = 8,
+    stats: Optional[runtime_lib.MatchStats] = None,
 ) -> Tuple[int, ...]:
     """1-d match count of every projection — d counting sweeps.
 
     Each count is the candidate-buffer size a sweep on that dimension would
     need; the counting sweep is O((n+m)·log(n+m)) per dimension, so probing
     all d dimensions costs far less than enumerating candidates on a wrong
-    (non-selective) one.
+    (non-selective) one.  With ``stats``, each count's blocking read runs
+    in its ``probe.readback`` span and counts in ``stats.readbacks``.
     """
-    return tuple(
-        int(sbm_count(subs.dim(d), upds.dim(d), num_segments=num_segments))
-        for d in range(subs.ndim_space)
-    )
+    counts = []
+    for d in range(subs.ndim_space):
+        count = sbm_count(subs.dim(d), upds.dim(d), num_segments=num_segments)
+        with (contextlib.nullcontext() if stats is None
+              else stats.readback("probe")):
+            counts.append(int(count))
+    return tuple(counts)
 
 
 def select_dimension(
-    subs: Extents, upds: Extents, *, num_segments: int = 8
+    subs: Extents, upds: Extents, *, num_segments: int = 8,
+    stats: Optional[runtime_lib.MatchStats] = None,
 ) -> Tuple[int, Tuple[int, ...]]:
     """(most selective dimension, per-dimension 1-d counts).
 
     The generator dimension is the argmin of the per-projection match
     counts (ties break toward the lower dimension index, making the choice
-    deterministic and the d=1 case the identity).
+    deterministic and the d=1 case the identity).  ``stats`` as in
+    :func:`per_dimension_counts`.
     """
-    counts = per_dimension_counts(subs, upds, num_segments=num_segments)
+    counts = per_dimension_counts(subs, upds, num_segments=num_segments,
+                                  stats=stats)
     return min(range(len(counts)), key=lambda d: counts[d]), counts
 
 
@@ -216,37 +225,41 @@ def enumerate_matches_ddim_planned(
     with the generator choice recorded as the stats ``regime``
     (DESIGN.md §10).
     """
-    import time as _time
-
     if method not in ("sweep", "bitmatrix", "blocked"):
         raise ValidationError(f"unknown method {method!r}")
-    t0 = _time.perf_counter()
+    stats = runtime_lib.MatchStats(engine="ddim")
     gen = generator_dim
-    if subs.size == 0 or upds.size == 0:
-        estimate = 0
-        regime = method
-    elif method == "bitmatrix":
-        estimate = int(bitmatrix_count(subs, upds))
-        regime = "bitmatrix"
-    elif subs.ndim_space == 1 or method == "blocked":
-        from repro.core.sweep import sbm_count_exact
+    with stats.phase("probe"):
+        if subs.size == 0 or upds.size == 0:
+            estimate = 0
+            regime = method
+        elif method == "bitmatrix":
+            count = bitmatrix_count(subs, upds)
+            with stats.readback("probe"):
+                estimate = int(count)
+            regime = "bitmatrix"
+        elif subs.ndim_space == 1 or method == "blocked":
+            from repro.core.sweep import probe_count
 
-        if method == "sweep":
-            estimate = sbm_count_exact(subs, upds,
+            if method == "sweep":
+                estimate = probe_count(subs, upds, stats,
                                        num_segments=num_segments)
+            else:
+                estimate = None
+            regime = method
         else:
-            estimate = None
-        regime = method
-    else:
-        if gen is None:
-            gen, counts = select_dimension(subs, upds,
-                                           num_segments=num_segments)
-            estimate = counts[gen]
-        else:
-            estimate = int(sbm_count(subs.dim(gen), upds.dim(gen),
-                                     num_segments=num_segments))
-        regime = f"sweep_dim{gen}"
-    probe_s = _time.perf_counter() - t0
+            if gen is None:
+                gen, counts = select_dimension(subs, upds,
+                                               num_segments=num_segments,
+                                               stats=stats)
+                estimate = counts[gen]
+            else:
+                count = sbm_count(subs.dim(gen), upds.dim(gen),
+                                  num_segments=num_segments)
+                with stats.readback("probe"):
+                    estimate = int(count)
+            regime = f"sweep_dim{gen}"
+    stats.regime = regime
 
     def fn(s, u, *, max_pairs):
         return enumerate_matches_ddim(
@@ -254,8 +267,8 @@ def enumerate_matches_ddim_planned(
             num_segments=num_segments, generator_dim=gen)
 
     return runtime_lib.execute_enumeration(
-        fn, subs, upds, estimate=estimate, policy=policy, engine="ddim",
-        regime=regime, probe_seconds=probe_s, recorder=recorder)
+        fn, subs, upds, estimate=estimate, policy=policy, stats=stats,
+        recorder=recorder)
 
 
 # ---------------------------------------------------------------------------

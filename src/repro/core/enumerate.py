@@ -78,33 +78,41 @@ def _sbm_enumerate_jit(subs: Extents, upds: Extents, *, max_pairs: int,
                        num_segments: int, scan_impl: str):
     n = subs.lo.shape[0]
     m = upds.lo.shape[0]
-    ep = _pad_stream(encode_endpoints(subs, upds), num_segments)
-    cumsum_fn = resolve_cumsum(scan_impl, num_segments)
-    a_start, a_cnt, b_start, b_cnt, subs_by_lo, upds_by_lo = \
-        emission_rank_tables(ep, n, m, cumsum_fn)
+    # named device stages (metadata only): the profiler reads them back
+    # from each instruction's op_name
+    with jax.named_scope("ddm.sort"):
+        ep = _pad_stream(encode_endpoints(subs, upds), num_segments)
+    with jax.named_scope("ddm.ranks"):
+        cumsum_fn = resolve_cumsum(scan_impl, num_segments)
+        a_start, a_cnt, b_start, b_cnt, subs_by_lo, upds_by_lo = \
+            emission_rank_tables(ep, n, m, cumsum_fn)
 
-    # Offset table: exclusive scan of per-emitter counts (emitters are the
-    # n subs then the m upds; the scan is over n+m entries, not the stream).
-    # Without x64 it saturates at 2^31-1 instead of wrapping (_offset_cumsum).
-    counts = jnp.concatenate([a_cnt, b_cnt])
-    off = _offset_cumsum(counts)
-    k_total = off[-1]
+        # Offset table: exclusive scan of per-emitter counts (emitters are
+        # the n subs then the m upds; the scan is over n+m entries, not the
+        # stream).  Without x64 it saturates at 2^31-1 instead of wrapping
+        # (_offset_cumsum).
+        counts = jnp.concatenate([a_cnt, b_cnt])
+        off = _offset_cumsum(counts)
+        k_total = off[-1]
 
     # Slot-parallel emission: slot s belongs to the emitter whose offset
     # range contains it; its rank within the emitter selects the counterpart
     # by lower-endpoint rank (a contiguous range — see emission_rank_tables).
-    slots = jnp.arange(max_pairs, dtype=jnp.int32)
-    e = jnp.searchsorted(off, slots, side="right").astype(jnp.int32)
-    e = jnp.minimum(e, n + m - 1)
-    r = slots - (off[e] - counts[e])
-    is_a = e < n
-    j_of_a = upds_by_lo[jnp.clip(a_start[jnp.minimum(e, n - 1)] + r, 0, m - 1)]
-    i_of_b = subs_by_lo[jnp.clip(b_start[jnp.clip(e - n, 0, m - 1)] + r,
-                                 0, n - 1)]
-    pi = jnp.where(is_a, e, i_of_b)
-    pj = jnp.where(is_a, j_of_a, e - n)
-    valid = slots < jnp.minimum(k_total, max_pairs)
-    pairs = jnp.where(valid[:, None], jnp.stack([pi, pj], axis=-1), -1)
+    with jax.named_scope("ddm.search"):
+        slots = jnp.arange(max_pairs, dtype=jnp.int32)
+        e = jnp.searchsorted(off, slots, side="right").astype(jnp.int32)
+        e = jnp.minimum(e, n + m - 1)
+    with jax.named_scope("ddm.gather"):
+        r = slots - (off[e] - counts[e])
+        is_a = e < n
+        j_of_a = upds_by_lo[jnp.clip(a_start[jnp.minimum(e, n - 1)] + r,
+                                     0, m - 1)]
+        i_of_b = subs_by_lo[jnp.clip(b_start[jnp.clip(e - n, 0, m - 1)] + r,
+                                     0, n - 1)]
+        pi = jnp.where(is_a, e, i_of_b)
+        pj = jnp.where(is_a, j_of_a, e - n)
+        valid = slots < jnp.minimum(k_total, max_pairs)
+        pairs = jnp.where(valid[:, None], jnp.stack([pi, pj], axis=-1), -1)
     return pairs, k_total
 
 
@@ -142,23 +150,24 @@ def sbm_enumerate_planned(subs: Extents, upds: Extents, *,
     """
     from repro.core.sweep import probe_count
 
+    stats = runtime_lib.MatchStats(engine="sweep")
     if subs.size == 0 or upds.size == 0:
-        stats = runtime_lib.MatchStats(engine="sweep", count=0, capacity=0)
         stats.add_phase("probe", 0.0)
         if recorder is not None:
             recorder.record(stats)
         return jnp.full((0, 2), -1, jnp.int32), jnp.int32(0), stats
 
-    k, probe_s = probe_count(subs, upds, num_segments=num_segments,
-                             scan_impl=scan_impl)
+    with stats.phase("probe"):
+        k = probe_count(subs, upds, stats, num_segments=num_segments,
+                        scan_impl=scan_impl)
 
     def fn(s, u, *, max_pairs):
         return sbm_enumerate(s, u, max_pairs=max_pairs,
                              num_segments=num_segments, scan_impl=scan_impl)
 
     return runtime_lib.execute_enumeration(
-        fn, subs, upds, estimate=k, policy=policy, engine="sweep",
-        probe_seconds=probe_s, recorder=recorder)
+        fn, subs, upds, estimate=k, policy=policy, stats=stats,
+        recorder=recorder)
 
 
 def sbm_enumerate_sharded(subs: Extents, upds: Extents, mesh, axis_name: str,

@@ -53,7 +53,6 @@ the rebuild path and the oracle every batch is property-tested against.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, Iterable, List, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
@@ -608,46 +607,46 @@ class IncrementalIndex:
                         side, changed_old[side])
 
         # splice the delta into the persistent stream + dense stores
-        t0 = time.perf_counter()
-        touched = self._delete_records_grouped(changed_old)
-        for side, rids in removes.items():
-            self._live[side][rids] = False
-            self._lo[side][:, rids] = np.inf
-            self._hi[side][:, rids] = -np.inf
-            if rids.size:
-                self._pack[side] = None       # liveness changed
-        inserts = {}
-        n_changed = 0
-        for side in _SIDES:
-            parts = [g for g in (moves.get(side), adds.get(side))
-                     if g is not None and g[0].size]
-            if not parts:
-                continue
-            rids = np.concatenate([p[0] for p in parts])
-            lo = np.concatenate([p[1] for p in parts], axis=1)
-            hi = np.concatenate([p[2] for p in parts], axis=1)
-            self._ensure_capacity(side, int(rids.max()))
-            self._lo[side][:, rids] = lo
-            self._hi[side][:, rids] = hi
-            self._live[side][rids] = True
-            inserts[side] = (rids, lo, hi)
-            if adds.get(side) is not None and adds[side][0].size:
-                self._pack[side] = None       # liveness changed
-            elif self._pack[side] is not None:
-                # moves only: patch the b changed columns in place —
-                # the packed view stays warm across move-heavy churn
-                cols = self._pack[side][1][rids]
-                self._pack[side][2][:, cols] = lo
-                self._pack[side][3][:, cols] = hi
-            n_changed += int(rids.size)
-        touched += self._insert_records_grouped(inserts)
-        self._prep = [None] * self.dims
-        self._cand_counts = [None] * self.dims
         splice_stats = runtime_lib.MatchStats(
-            engine="incremental_splice", regime=self.index_impl,
-            count=n_changed + sum(int(r.size) for r in removes.values()),
-            blocks_touched=touched)
-        splice_stats.add_phase("splice", time.perf_counter() - t0)
+            engine="incremental_splice", regime=self.index_impl)
+        with splice_stats.phase("splice"):
+            touched = self._delete_records_grouped(changed_old)
+            for side, rids in removes.items():
+                self._live[side][rids] = False
+                self._lo[side][:, rids] = np.inf
+                self._hi[side][:, rids] = -np.inf
+                if rids.size:
+                    self._pack[side] = None       # liveness changed
+            inserts = {}
+            n_changed = 0
+            for side in _SIDES:
+                parts = [g for g in (moves.get(side), adds.get(side))
+                         if g is not None and g[0].size]
+                if not parts:
+                    continue
+                rids = np.concatenate([p[0] for p in parts])
+                lo = np.concatenate([p[1] for p in parts], axis=1)
+                hi = np.concatenate([p[2] for p in parts], axis=1)
+                self._ensure_capacity(side, int(rids.max()))
+                self._lo[side][:, rids] = lo
+                self._hi[side][:, rids] = hi
+                self._live[side][rids] = True
+                inserts[side] = (rids, lo, hi)
+                if adds.get(side) is not None and adds[side][0].size:
+                    self._pack[side] = None       # liveness changed
+                elif self._pack[side] is not None:
+                    # moves only: patch the b changed columns in place —
+                    # the packed view stays warm across move-heavy churn
+                    cols = self._pack[side][1][rids]
+                    self._pack[side][2][:, cols] = lo
+                    self._pack[side][3][:, cols] = hi
+                n_changed += int(rids.size)
+            touched += self._insert_records_grouped(inserts)
+            self._prep = [None] * self.dims
+            self._cand_counts = [None] * self.dims
+        splice_stats.count = n_changed + sum(int(r.size)
+                                             for r in removes.values())
+        splice_stats.blocks_touched = touched
         self.last_batch_stats = splice_stats
         self.recorder.record(splice_stats)
 
@@ -672,16 +671,16 @@ class IncrementalIndex:
                          rids: np.ndarray) -> Set[Tuple[int, int]]:
         """Match sets of changed rids vs live counterparts, impl-dispatched."""
         if self.delta_impl == "loop":
-            t0 = time.perf_counter()
-            out: Set[Tuple[int, int]] = set()
-            for rid in rids.tolist():
-                out |= self._matches_of(side, rid)
             # same observability contract as the stacked paths: every
             # rematch phase is a MatchStats, whichever impl ran it
-            stats = runtime_lib.MatchStats(
-                engine="incremental_bulk", regime="loop",
-                count=len(out), capacity=len(out), attempts=[len(out)])
-            stats.add_phase("rematch", time.perf_counter() - t0)
+            stats = runtime_lib.MatchStats(engine="incremental_bulk",
+                                           regime="loop")
+            out: Set[Tuple[int, int]] = set()
+            with stats.phase("rematch"):
+                for rid in rids.tolist():
+                    out |= self._matches_of(side, rid)
+            stats.count = stats.capacity = len(out)
+            stats.attempts = [len(out)]
             self.recorder.record(stats)
             return out
         return self._matches_of_many(side, rids)
@@ -745,23 +744,22 @@ class IncrementalIndex:
     def _prep_tables(self, dim: int = 0) -> _Prep:
         if self._prep[dim] is not None:
             return self._prep[dim]
-        t0 = time.perf_counter()
-        cap_s = self._live[SUB].shape[0]
-        cap_u = self._live[UPD].shape[0]
-        # the stream backend owns table construction: one whole-stream
-        # cumsum pass (flat) or per-block cached locals + prefix-offset
-        # assembly, recomputing only dirty blocks (blocked, DESIGN.md §13)
-        rt = self._streams[dim].rank_tables(cap_s, cap_u)
-        self._prep[dim] = _Prep(
-            subs_by_lo=rt.subs_by_lo, upds_by_lo=rt.upds_by_lo,
-            a_start=rt.a_start, a_end=rt.a_end,
-            b_start=rt.b_start, b_end=rt.b_end,
-            live_s=self.live_ids(SUB), live_u=self.live_ids(UPD))
-        stats = runtime_lib.MatchStats(
-            engine="incremental_prep", regime=self.index_impl,
-            count=int(rt.subs_by_lo.size + rt.upds_by_lo.size),
-            blocks_touched=rt.patched_blocks)
-        stats.add_phase("rank_patch", time.perf_counter() - t0)
+        stats = runtime_lib.MatchStats(engine="incremental_prep",
+                                       regime=self.index_impl)
+        with stats.phase("rank_patch"):
+            cap_s = self._live[SUB].shape[0]
+            cap_u = self._live[UPD].shape[0]
+            # the stream backend owns table construction: one whole-stream
+            # cumsum pass (flat) or per-block cached locals + prefix-offset
+            # assembly, recomputing only dirty blocks (blocked, DESIGN.md §13)
+            rt = self._streams[dim].rank_tables(cap_s, cap_u)
+            self._prep[dim] = _Prep(
+                subs_by_lo=rt.subs_by_lo, upds_by_lo=rt.upds_by_lo,
+                a_start=rt.a_start, a_end=rt.a_end,
+                b_start=rt.b_start, b_end=rt.b_end,
+                live_s=self.live_ids(SUB), live_u=self.live_ids(UPD))
+        stats.count = int(rt.subs_by_lo.size + rt.upds_by_lo.size)
+        stats.blocks_touched = rt.patched_blocks
         self.recorder.record(stats)
         return self._prep[dim]
 
@@ -837,14 +835,13 @@ class IncrementalIndex:
         rids = np.asarray(rids, np.int64)
         if lv.size == 0 or rids.size == 0:
             return set()
-        t0 = time.perf_counter()
-        qi, cj, regime = _bulk_overlap_pairs(
-            self._lo[side][:, rids], self._hi[side][:, rids],
-            p_lo, p_hi, self.regime_policy)
-        stats = runtime_lib.MatchStats(
-            engine="incremental_bulk", regime=regime, count=int(qi.size),
-            capacity=int(qi.size), attempts=[int(qi.size)])
-        stats.add_phase("rematch", time.perf_counter() - t0)
+        stats = runtime_lib.MatchStats(engine="incremental_bulk")
+        with stats.phase("rematch"):
+            qi, cj, stats.regime = _bulk_overlap_pairs(
+                self._lo[side][:, rids], self._hi[side][:, rids],
+                p_lo, p_hi, self.regime_policy)
+        stats.count = stats.capacity = int(qi.size)
+        stats.attempts = [int(qi.size)]
         self.recorder.record(stats)
         qs, cs = rids[qi], lv[cj]
         if side == SUB:
@@ -875,80 +872,81 @@ class IncrementalIndex:
         b, m = int(rids.size), int(lv.size)
         if b == 0 or m == 0:
             return set(), set()
-        t0 = time.perf_counter()
-        regime = runtime_lib.select_bulk_regime(b, m, self.regime_policy)
-        if regime == "sort":
-            qi_o, cj_o = _sorted_overlap_pairs(old_lo, old_hi, p_lo, p_hi)
-            qi_n, cj_n = _sorted_overlap_pairs(new_lo, new_hi, p_lo, p_hi)
-            was = set(zip(qi_o.tolist(), cj_o.tolist()))
-            now = set(zip(qi_n.tolist(), cj_n.tolist()))
-            add_pairs = now - was
-            rem_pairs = was - now
-            qi_a = np.fromiter((p[0] for p in add_pairs), np.int64,
-                               len(add_pairs))
-            cj_a = np.fromiter((p[1] for p in add_pairs), np.int64,
-                               len(add_pairs))
-            qi_r = np.fromiter((p[0] for p in rem_pairs), np.int64,
-                               len(rem_pairs))
-            cj_r = np.fromiter((p[1] for p in rem_pairs), np.int64,
-                               len(rem_pairs))
-        elif regime == "dense":
-            was = ((p_lo[0][None, :] <= old_hi[0][:, None]) &
-                   (old_lo[0][:, None] <= p_hi[0][None, :]))
-            now = ((p_lo[0][None, :] <= new_hi[0][:, None]) &
-                   (new_lo[0][:, None] <= p_hi[0][None, :]))
-            for d in range(1, self.dims):
-                was &= ((p_lo[d][None, :] <= old_hi[d][:, None]) &
-                        (old_lo[d][:, None] <= p_hi[d][None, :]))
-                now &= ((p_lo[d][None, :] <= new_hi[d][:, None]) &
-                        (new_lo[d][:, None] <= p_hi[d][None, :]))
-            flat = np.flatnonzero(was ^ now)
-            grew = now.ravel()[flat]          # True → added, False → removed
-            qi, cj = np.divmod(flat, m)
-            qi_a, cj_a = qi[grew], cj[grew]
-            qi_r, cj_r = qi[~grew], cj[~grew]
-        else:
-            global _fused_delta
-            if _fused_delta is None:
-                _fused_delta = _make_fused_delta()
-            bp, mp = _round_up_pow2(b), _round_up_pow2(m)
-            cl_pad = _pad_cols(p_lo, mp, np.inf)
-            ch_pad = _pad_cols(p_hi, mp, -np.inf)
-            flags = np.asarray(_fused_delta(
-                _pad_cols(old_lo, bp, np.inf), _pad_cols(old_hi, bp, -np.inf),
-                _pad_cols(new_lo, bp, np.inf), _pad_cols(new_hi, bp, -np.inf),
-                cl_pad, ch_pad))
-            ck = mp // flags.shape[1]
-            ri, ki = np.nonzero(flags)
-            # recompute only the flipped chunks on the host: each flag
-            # covers (moved region ri, counterpart columns [ki*ck, +ck)),
-            # so the numpy re-evaluation touches ~hits·CH cells, not b·m
-            col0 = ki * ck
-            gidx = col0[:, None] + np.arange(ck)
-            was = np.ones((ri.size, ck), bool)
-            now = np.ones((ri.size, ck), bool)
-            for d in range(self.dims):
-                cl, chh = cl_pad[d][gidx], ch_pad[d][gidx]
-                was &= ((cl <= old_hi[d][ri][:, None]) &
-                        (old_lo[d][ri][:, None] <= chh))
-                now &= ((cl <= new_hi[d][ri][:, None]) &
-                        (new_lo[d][ri][:, None] <= chh))
-            rr, cc = np.nonzero(was ^ now)
-            qi, cj = ri[rr], col0[rr] + cc
-            grew = now[rr, cc]
-            # same sentinel caveat as the fused mask: filter padded
-            # row/column indices explicitly rather than reasoning about
-            # which inf-bound combinations can flip
-            keep = (qi < b) & (cj < m)
-            qi, cj, grew = qi[keep], cj[keep], grew[keep]
-            qi_a, cj_a = qi[grew], cj[grew]
-            qi_r, cj_r = qi[~grew], cj[~grew]
-        stats = runtime_lib.MatchStats(
-            engine="incremental_bulk", regime=regime,
-            count=int(qi_a.size + qi_r.size),
-            capacity=int(qi_a.size + qi_r.size),
-            attempts=[int(qi_a.size + qi_r.size)])
-        stats.add_phase("rematch", time.perf_counter() - t0)
+        stats = runtime_lib.MatchStats(engine="incremental_bulk")
+        with stats.phase("rematch"):
+            regime = runtime_lib.select_bulk_regime(b, m, self.regime_policy)
+            if regime == "sort":
+                qi_o, cj_o = _sorted_overlap_pairs(old_lo, old_hi, p_lo, p_hi)
+                qi_n, cj_n = _sorted_overlap_pairs(new_lo, new_hi, p_lo, p_hi)
+                was = set(zip(qi_o.tolist(), cj_o.tolist()))
+                now = set(zip(qi_n.tolist(), cj_n.tolist()))
+                add_pairs = now - was
+                rem_pairs = was - now
+                qi_a = np.fromiter((p[0] for p in add_pairs), np.int64,
+                                   len(add_pairs))
+                cj_a = np.fromiter((p[1] for p in add_pairs), np.int64,
+                                   len(add_pairs))
+                qi_r = np.fromiter((p[0] for p in rem_pairs), np.int64,
+                                   len(rem_pairs))
+                cj_r = np.fromiter((p[1] for p in rem_pairs), np.int64,
+                                   len(rem_pairs))
+            elif regime == "dense":
+                was = ((p_lo[0][None, :] <= old_hi[0][:, None]) &
+                       (old_lo[0][:, None] <= p_hi[0][None, :]))
+                now = ((p_lo[0][None, :] <= new_hi[0][:, None]) &
+                       (new_lo[0][:, None] <= p_hi[0][None, :]))
+                for d in range(1, self.dims):
+                    was &= ((p_lo[d][None, :] <= old_hi[d][:, None]) &
+                            (old_lo[d][:, None] <= p_hi[d][None, :]))
+                    now &= ((p_lo[d][None, :] <= new_hi[d][:, None]) &
+                            (new_lo[d][:, None] <= p_hi[d][None, :]))
+                flat = np.flatnonzero(was ^ now)
+                grew = now.ravel()[flat]          # True → added, False → removed
+                qi, cj = np.divmod(flat, m)
+                qi_a, cj_a = qi[grew], cj[grew]
+                qi_r, cj_r = qi[~grew], cj[~grew]
+            else:
+                global _fused_delta
+                if _fused_delta is None:
+                    _fused_delta = _make_fused_delta()
+                bp, mp = _round_up_pow2(b), _round_up_pow2(m)
+                cl_pad = _pad_cols(p_lo, mp, np.inf)
+                ch_pad = _pad_cols(p_hi, mp, -np.inf)
+                flags = _fused_delta(
+                    _pad_cols(old_lo, bp, np.inf),
+                    _pad_cols(old_hi, bp, -np.inf),
+                    _pad_cols(new_lo, bp, np.inf),
+                    _pad_cols(new_hi, bp, -np.inf), cl_pad, ch_pad)
+                with stats.readback("rematch"):
+                    flags = np.asarray(flags)
+                ck = mp // flags.shape[1]
+                ri, ki = np.nonzero(flags)
+                # recompute only the flipped chunks on the host: each flag
+                # covers (moved region ri, counterpart columns [ki*ck, +ck)),
+                # so the numpy re-evaluation touches ~hits·CH cells, not b·m
+                col0 = ki * ck
+                gidx = col0[:, None] + np.arange(ck)
+                was = np.ones((ri.size, ck), bool)
+                now = np.ones((ri.size, ck), bool)
+                for d in range(self.dims):
+                    cl, chh = cl_pad[d][gidx], ch_pad[d][gidx]
+                    was &= ((cl <= old_hi[d][ri][:, None]) &
+                            (old_lo[d][ri][:, None] <= chh))
+                    now &= ((cl <= new_hi[d][ri][:, None]) &
+                            (new_lo[d][ri][:, None] <= chh))
+                rr, cc = np.nonzero(was ^ now)
+                qi, cj = ri[rr], col0[rr] + cc
+                grew = now[rr, cc]
+                # same sentinel caveat as the fused mask: filter padded
+                # row/column indices explicitly rather than reasoning about
+                # which inf-bound combinations can flip
+                keep = (qi < b) & (cj < m)
+                qi, cj, grew = qi[keep], cj[keep], grew[keep]
+                qi_a, cj_a = qi[grew], cj[grew]
+                qi_r, cj_r = qi[~grew], cj[~grew]
+        stats.regime = regime
+        stats.count = stats.capacity = int(qi_a.size + qi_r.size)
+        stats.attempts = [stats.count]
         self.recorder.record(stats)
 
         def orient(qs, cs):

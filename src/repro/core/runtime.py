@@ -20,11 +20,17 @@ is the single home for all of it (DESIGN.md §10):
 * **Executor** — :func:`execute_enumeration` is the one true
   count-then-retry loop (promoted out of the test harness; the
   conformance registry now runs the production path).  Every call records
-  a :class:`MatchStats`: per-phase wall times, retry count, jit
-  recompiles (via the compile-cache probe :func:`jit_compiles`), final
-  capacity, and padded-vs-actual waste.
-* **Observability** — :class:`StatsRecorder` aggregates stats across
-  calls; :meth:`repro.core.service.DDMService.stats` surfaces one.
+  a :class:`MatchStats`: per-phase wall times, blocking device→host
+  readbacks, retry count, jit recompiles (via the compile-cache probe
+  :func:`jit_compiles`), final capacity, and padded-vs-actual waste.
+* **Observability** — :class:`MatchStats` is the one recorder.  Its
+  :meth:`MatchStats.phase` times a phase into ``phase_seconds`` and opens
+  the host span ``ddm.<phase>`` on the profiler's clock;
+  :meth:`MatchStats.readback` wraps a blocking device→host read in the
+  child span ``ddm.<phase>.readback`` and counts it.  Spans carry the
+  call's ``engine`` and per-process ``call`` number as metadata.
+  :class:`StatsRecorder` aggregates stats across calls;
+  :meth:`repro.core.service.DDMService.stats` surfaces one.
 * **Bulk-regime policy** — :class:`BulkRegimePolicy` owns the
   dense/jax/sort thresholds of the incremental engine's stacked rematch
   (:func:`repro.core.incremental._bulk_overlap_pairs`), so the three
@@ -36,17 +42,26 @@ and offsets+emit fuse into each enumeration attempt under jit, so the
 wall-clock split observable from the host is ``probe`` (sort + count),
 ``emit`` (offset table + pair emission, summed over retry attempts) and
 ``collect`` (host-side pair-set materialization, when requested).
+Inside the two programs, ``jax.named_scope`` names the device stages
+``ddm.sort``, ``ddm.count`` (probe), ``ddm.ranks``, ``ddm.search`` and
+``ddm.gather`` (emission); the names reach the profiler as each
+instruction's ``op_name``.
 
 This module stays import-light (stdlib + numpy at module scope; jax is
 imported lazily) so host-only paths like the incremental index keep their
-no-jax-at-import property.
+no-jax-at-import property: where jax was never imported, a phase only
+times.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import itertools
+import sys
 import time
 from collections import deque
-from typing import Callable, Deque, Dict, List, Optional, Set, Tuple
+from typing import (Callable, ContextManager, Deque, Dict, Iterator, List,
+                    Optional, Set, Tuple)
 
 import numpy as np
 
@@ -186,8 +201,9 @@ def jit_compiles() -> int:
     """Monotonic count of XLA backend compiles since the probe was armed.
 
     Deltas across a region of code count the jit recompiles it caused —
-    zero after warmup is the ladder's whole point, and the CI bench gate
-    enforces it (``benchmarks/check_regression.py``).
+    zero after warmup is the ladder's whole point.
+    :func:`execute_enumeration` records each call's delta as
+    ``MatchStats.recompiles``.
     """
     _arm_compile_probe()
     return _compile_probe["count"]
@@ -196,6 +212,20 @@ def jit_compiles() -> int:
 # ---------------------------------------------------------------------------
 # Per-call stats + the aggregating recorder
 # ---------------------------------------------------------------------------
+
+_CALLS = itertools.count(1)       # per-process call numbers of MatchStats
+_annotation = {"cls": None}
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` once jax is imported, else None —
+    a host-only process never imports jax for a span."""
+    if _annotation["cls"] is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        _annotation["cls"] = TraceAnnotation
+    return _annotation["cls"]
+
 
 @dataclasses.dataclass
 class MatchStats:
@@ -211,6 +241,10 @@ class MatchStats:
     incremental index's ``splice``/``rank_patch`` surgery phases).
     ``blocks_touched`` counts the blocked endpoint index's per-batch
     block mutations (0 for non-blocked engines; DESIGN.md §13).
+    ``readbacks`` counts the blocking device→host reads the call made
+    before it returned (a planned 1-d sweep: the probe's four count
+    partials, then the emission's count).  ``call`` is a per-process
+    number that the call's profiler spans carry with its ``engine``.
     """
 
     engine: str = ""
@@ -220,8 +254,11 @@ class MatchStats:
     retries: int = 0
     recompiles: int = 0
     blocks_touched: int = 0
+    readbacks: int = 0
     attempts: List[int] = dataclasses.field(default_factory=list)
     phase_seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
+    call: int = dataclasses.field(default_factory=lambda: next(_CALLS),
+                                  compare=False, repr=False)
 
     @property
     def waste(self) -> int:
@@ -247,6 +284,30 @@ class MatchStats:
     def add_phase(self, name: str, seconds: float) -> None:
         self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) + seconds
 
+    @contextlib.contextmanager
+    def phase(self, name: str) -> Iterator[None]:
+        """Time the enclosed code into ``phase_seconds[name]`` (wall
+        seconds, summed over repeats) inside the host span ``ddm.<name>``.
+        A body that raises records no time."""
+        with self._span(name):
+            t0 = time.perf_counter()
+            yield
+            self.add_phase(name, time.perf_counter() - t0)
+
+    @contextlib.contextmanager
+    def readback(self, phase: str, n: int = 1) -> Iterator[None]:
+        """Count ``n`` blocking device→host reads made by the enclosed code
+        and put them in the span ``ddm.<phase>.readback``."""
+        self.readbacks += n
+        with self._span(f"{phase}.readback"):
+            yield
+
+    def _span(self, name: str) -> ContextManager:
+        annotation = _trace_annotation()
+        if annotation is None:
+            return contextlib.nullcontext()
+        return annotation(f"ddm.{name}", engine=self.engine, call=self.call)
+
     def as_dict(self) -> Dict[str, object]:
         return {
             "engine": self.engine,
@@ -256,6 +317,7 @@ class MatchStats:
             "retries": self.retries,
             "recompiles": self.recompiles,
             "blocks_touched": self.blocks_touched,
+            "readbacks": self.readbacks,
             "attempts": list(self.attempts),
             "waste": self.waste,
             "peak_buffer_elements": self.peak_buffer_elements,
@@ -327,7 +389,7 @@ def execute_enumeration(
     policy: CapacityPolicy = DEFAULT_POLICY,
     engine: str = "",
     regime: str = "",
-    probe_seconds: float = 0.0,
+    stats: Optional[MatchStats] = None,
     recorder: Optional[StatsRecorder] = None,
 ):
     """Run ``fn(subs, upds, max_pairs=c) -> (buffer, count)`` under the
@@ -345,23 +407,24 @@ def execute_enumeration(
     Returns ``(buffer, count, stats)``; the buffer/count are the last
     attempt's device results (buffer padded with ``(-1, -1)``).  Raises
     :class:`CapacityError` on a hard-cap violation or when
-    ``policy.max_attempts`` is exhausted.  ``probe_seconds`` seeds the
-    ``probe`` phase time when the caller already ran the estimate's
-    counting sweep; ``recorder`` (when given) receives the stats.
+    ``policy.max_attempts`` is exhausted.  Each attempt runs in the
+    ``emit`` phase, its count read in ``emit.readback``.  A caller that
+    ran the estimate's counting sweep in a ``probe`` phase hands that
+    record in as ``stats`` (named there; ``engine``/``regime`` name a
+    fresh record otherwise); ``recorder`` (when given) receives the stats.
     """
-    stats = MatchStats(engine=engine, regime=regime)
-    if probe_seconds:
-        stats.add_phase("probe", probe_seconds)
+    if stats is None:
+        stats = MatchStats(engine=engine, regime=regime)
     cap = (int(capacity) if capacity is not None
            else initial_capacity(estimate, policy))
     _arm_compile_probe()
     compiles_before = jit_compiles()
     for attempt in range(max(policy.max_attempts, 1)):
         stats.attempts.append(cap)
-        t0 = time.perf_counter()
-        buf, count = fn(subs, upds, max_pairs=cap)
-        c = int(count)                       # device sync: closes the phase
-        stats.add_phase("emit", time.perf_counter() - t0)
+        with stats.phase("emit"):
+            buf, count = fn(subs, upds, max_pairs=cap)
+            with stats.readback("emit"):
+                c = int(count)               # device sync: closes the phase
         if c <= cap:
             stats.count = c
             stats.capacity = cap
@@ -373,7 +436,7 @@ def execute_enumeration(
         cap = next_capacity(c, cap, policy)
     raise CapacityError(
         f"enumeration never satisfied count <= max_pairs within "
-        f"{policy.max_attempts} attempts (engine {engine!r}, "
+        f"{policy.max_attempts} attempts (engine {stats.engine!r}, "
         f"attempts {stats.attempts})")
 
 
@@ -402,9 +465,10 @@ def pairs_via_retry(fn, subs, upds, *, start_cap: int = 64,
     policy = policy or DEFAULT_POLICY
     buf, count, stats = execute_enumeration(
         fn, subs, upds, capacity=start_cap, policy=policy, engine=engine)
-    t0 = time.perf_counter()
-    got = pair_set(buf)
-    stats.add_phase("collect", time.perf_counter() - t0)
+    with stats.phase("collect"):
+        with stats.readback("collect"):
+            host = np.asarray(buf)
+        got = pair_set(host)
     if recorder is not None:
         recorder.record(stats)
     c = int(count)

@@ -33,7 +33,6 @@ lifting runs in jitted JAX.
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
 
@@ -561,18 +560,19 @@ class DDMService:
         gate asserts.  Stats land in the service recorder under
         ``engine``; d > 1 records the generator dimension as the regime.
         """
-        t0 = time.perf_counter()
-        if self.dims == 1:
-            gen, k = 0, int(sweep_lib.sbm_count(subs, upds))
-            regime = "sweep_1d"
-        else:
-            gen, counts = ddim_lib.select_dimension(subs, upds)
-            k = counts[gen]
-            regime = f"sweep_dim{gen}"
-        probe_s = time.perf_counter() - t0
+        stats = runtime_lib.MatchStats(engine=engine)
+        with stats.phase("probe"):
+            if self.dims == 1:
+                count = sweep_lib.sbm_count(subs, upds)
+                with stats.readback("probe"):
+                    gen, k = 0, int(count)
+                stats.regime = "sweep_1d"
+            else:
+                gen, counts = ddim_lib.select_dimension(subs, upds,
+                                                        stats=stats)
+                k = counts[gen]
+                stats.regime = f"sweep_dim{gen}"
         if k == 0:
-            stats = runtime_lib.MatchStats(engine=engine, regime=regime)
-            stats.add_phase("probe", probe_s)
             self._recorder.record(stats)
             return None, 0, stats
 
@@ -582,8 +582,8 @@ class DDMService:
                 generator_dim=gen)
 
         return runtime_lib.execute_enumeration(
-            fn, subs, upds, estimate=k, policy=self._policy, engine=engine,
-            regime=regime, probe_seconds=probe_s, recorder=self._recorder)
+            fn, subs, upds, estimate=k, policy=self._policy, stats=stats,
+            recorder=self._recorder)
 
     def _sweep_pairs(self, subs: Extents, upds: Extents):
         """(i, j) index pairs over compacted live extents via the sweep.

@@ -180,11 +180,15 @@ def combine_lane_partials(a, b, c, d):
 @functools.partial(jax.jit, static_argnames=("num_segments", "scan_impl"))
 def _sbm_count_partials(subs: Extents, upds: Extents, *, num_segments: int,
                         scan_impl: str):
-    ep = _pad_stream(encode_endpoints(subs, upds), num_segments)
-    sub_lo, sub_up, upd_lo, upd_up = _indicator_deltas(ep)
-    cumsum_fn = resolve_cumsum(scan_impl, num_segments)
-    emit = _emission_counts(sub_lo, sub_up, upd_lo, upd_up, cumsum_fn)
-    return _lane_partial_sums(emit)
+    # named device stages (metadata only): the profiler reads them back
+    # from each instruction's op_name
+    with jax.named_scope("ddm.sort"):
+        ep = _pad_stream(encode_endpoints(subs, upds), num_segments)
+    with jax.named_scope("ddm.count"):
+        sub_lo, sub_up, upd_lo, upd_up = _indicator_deltas(ep)
+        cumsum_fn = resolve_cumsum(scan_impl, num_segments)
+        emit = _emission_counts(sub_lo, sub_up, upd_lo, upd_up, cumsum_fn)
+        return _lane_partial_sums(emit)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "scan_impl"))
@@ -206,22 +210,27 @@ def sbm_count(subs: Extents, upds: Extents, *, num_segments: int = 8,
     return combine_lane_partials(a, b, c, d)
 
 
-def probe_count(subs: Extents, upds: Extents, *, num_segments: int = 8,
-                scan_impl: str = "two_level") -> tuple:
-    """Plan-aware counting sweep: ``(K, seconds)`` for the runtime planner.
+def probe_count(subs: Extents, upds: Extents, stats, *,
+                num_segments: int = 8, scan_impl: str = "two_level") -> int:
+    """Plan-aware counting sweep: the exact K for the runtime planner.
 
     The cheap selectivity probe of DESIGN.md §10 — one fused sort+count
     pass whose exact K seeds :func:`repro.core.runtime.initial_capacity`
-    (so the follow-on enumeration needs zero retries) and whose wall time
-    becomes the ``probe`` phase of the call's
-    :class:`repro.core.runtime.MatchStats`.
+    (so the follow-on enumeration needs zero retries).  The caller times
+    it in ``stats.phase("probe")``; the four blocking partial reads run in
+    the ``probe.readback`` span and count in ``stats.readbacks``.
     """
-    import time
+    if subs.lo.shape[-1] == 0 or upds.lo.shape[-1] == 0:
+        return 0
+    partials = _sbm_count_partials(subs, upds, num_segments=num_segments,
+                                   scan_impl=scan_impl)
+    with stats.readback("probe", len(partials)):
+        return _exact_count(*partials)
 
-    t0 = time.perf_counter()
-    k = sbm_count_exact(subs, upds, num_segments=num_segments,
-                        scan_impl=scan_impl)
-    return k, time.perf_counter() - t0
+
+def _exact_count(a, b, c, d) -> int:
+    """K from the four lane partials, combined in Python integers."""
+    return (int(a) << 32) + ((int(b) + int(c)) << 16) + int(d)
 
 
 def sbm_count_exact(subs: Extents, upds: Extents, *, num_segments: int = 8,
@@ -234,9 +243,8 @@ def sbm_count_exact(subs: Extents, upds: Extents, *, num_segments: int = 8,
     """
     if subs.lo.shape[-1] == 0 or upds.lo.shape[-1] == 0:
         return 0
-    a, b, c, d = _sbm_count_partials(subs, upds, num_segments=num_segments,
-                                     scan_impl=scan_impl)
-    return (int(a) << 32) + ((int(b) + int(c)) << 16) + int(d)
+    return _exact_count(*_sbm_count_partials(
+        subs, upds, num_segments=num_segments, scan_impl=scan_impl))
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments",))

@@ -398,41 +398,43 @@ class BrokerSession:
     def _flush_locked(self) -> BatchDelta:
         self._assert_lock_held()
         self._check_usable_locked()
-        t0 = time.perf_counter()
-        now = self._clock()
-        ops = list(self._queue)
-        self._queue.clear()
-        applied: List[Tuple[_Op, object]] = []
-        for op in ops:
-            if op.deadline is not None and now > op.deadline:
-                self.expired += 1
-                op.ticket._fail(DeadlineExceeded(
-                    f"session {self.name!r}: {op.kind} deadline passed "
-                    "before the flush that would have applied it"))
-                continue
+        stats = runtime_lib.MatchStats(
+            engine="frontend_flush", regime=self.admission.backpressure)
+        with stats.phase("flush"):
+            now = self._clock()
+            ops = list(self._queue)
+            self._queue.clear()
+            applied: List[Tuple[_Op, object]] = []
+            for op in ops:
+                if op.deadline is not None and now > op.deadline:
+                    self.expired += 1
+                    op.ticket._fail(DeadlineExceeded(
+                        f"session {self.name!r}: {op.kind} deadline passed "
+                        "before the flush that would have applied it"))
+                    continue
+                try:
+                    result = self._apply_op(op)
+                except Exception as exc:           # bad rid/bounds: op-local
+                    self.failed += 1
+                    op.ticket._fail(exc)
+                    continue
+                applied.append((op, result))
+            # cleared so an empty flush can't fold a previous batch's surgery
+            # stats into this record
+            self._svc._index.last_batch_stats = None
             try:
-                result = self._apply_op(op)
-            except Exception as exc:           # bad rid/bounds: op-local
-                self.failed += 1
-                op.ticket._fail(exc)
-                continue
-            applied.append((op, result))
-        # cleared so an empty flush can't fold a previous batch's surgery
-        # stats into this record
-        self._svc._index.last_batch_stats = None
-        try:
-            delta = self._svc.flush()
-        except Exception as exc:
-            # the service may have applied part of the batch to its tables
-            # (and handed out rids) but not to its index: fail every op of
-            # the batch and break the session rather than serve that state
-            self.broken = exc
-            self.failed += len(applied)
-            for op, _result in applied:
-                op.ticket._fail(exc)
-            self._space.notify_all()
-            raise
-        dt = time.perf_counter() - t0
+                delta = self._svc.flush()
+            except Exception as exc:
+                # the service may have applied part of the batch to its tables
+                # (and handed out rids) but not to its index: fail every op of
+                # the batch and break the session rather than serve that state
+                self.broken = exc
+                self.failed += len(applied)
+                for op, _result in applied:
+                    op.ticket._fail(exc)
+                self._space.notify_all()
+                raise
+        dt = stats.phase_seconds["flush"]
         self._flush_seconds.append(dt)
         self.flushes += 1
         self.applied += len(applied)
@@ -445,11 +447,9 @@ class BrokerSession:
                     "hi": None if op.hi is None else op.hi.tolist(),
                 })
             op.ticket._resolve(result)
-        stats = runtime_lib.MatchStats(
-            engine="frontend_flush", regime=self.admission.backpressure,
-            count=len(applied), capacity=len(ops),
-            attempts=[len(ops)])
-        stats.add_phase("flush", dt)
+        stats.count = len(applied)
+        stats.capacity = len(ops)
+        stats.attempts = [len(ops)]
         # fold the index's surgery stats into the flush record so the
         # broker surface shows blocked-index behaviour (DESIGN.md §13)
         surgery = self._svc._index.last_batch_stats
@@ -521,17 +521,18 @@ class BrokerSession:
                 self._flush_locked()
                 self.exact_reads += 1
                 return CountResult(self._svc.match_count(), True, "index", 0)
-            t0 = time.perf_counter()
-            count, source = self._estimate_locked()
+            stats = runtime_lib.MatchStats(engine="frontend_degraded_read")
+            with stats.phase("probe"):
+                count, source = self._estimate_locked(stats)
             self.degraded_reads += 1
-            stats = runtime_lib.MatchStats(
-                engine="frontend_degraded_read", regime=source, count=count)
-            stats.add_phase("probe", time.perf_counter() - t0)
+            stats.regime, stats.count = source, count
             self._record(stats)
             return CountResult(count, False, source, len(self._queue))
 
-    def _estimate_locked(self) -> Tuple[int, str]:
-        """The degradation ladder's cheap count over applied state."""
+    def _estimate_locked(self, stats: runtime_lib.MatchStats
+                         ) -> Tuple[int, str]:
+        """The degradation ladder's cheap count over applied state; its
+        blocking device reads count in ``stats``."""
         from repro.core import ddim as ddim_lib
         from repro.core import sweep as sweep_lib
         from repro.core.grid import grid_count
@@ -545,11 +546,11 @@ class BrokerSession:
         upds = svc._upds.compact(ul)
         if svc.dims == 1 and self.degrade.estimator == "grid":
             count, _ = grid_count(subs, upds)     # overflow → lower bound
-            return int(count), "grid_count"
+            with stats.readback("probe"):
+                return int(count), "grid_count"
         if svc.dims == 1:
-            k, _ = sweep_lib.probe_count(subs, upds)
-            return int(k), "probe_count"
-        gen, counts = ddim_lib.select_dimension(subs, upds)
+            return sweep_lib.probe_count(subs, upds, stats), "probe_count"
+        gen, counts = ddim_lib.select_dimension(subs, upds, stats=stats)
         return int(counts[gen]), "probe_count"    # min_d K_d: upper bound
 
     # -- observability -----------------------------------------------------
