@@ -8,12 +8,15 @@ precomputed slot.  Output buffers are padded to a static ``max_pairs``.
 Two engines behind the same (pairs, count) contract:
 
 * :func:`sbm_enumerate` — the sort-based sweep, output-sensitive
-  O((n+m)·log(n+m) + K).  Per-extent emission counts come from the same
-  indicator cumsums as :func:`repro.core.sweep.sbm_count`; their exclusive
-  scan is the offset table and a slot-parallel gather materializes the
-  pairs (DESIGN.md §3).  :func:`sbm_enumerate_sharded` runs the same scheme
-  across a device mesh axis; :func:`repro.kernels.sbm_enumerate_kernel` is
-  the Pallas on-chip form.
+  O((n+m)·log(n+m) + max_pairs).  Per-extent emission counts come from the
+  same indicator cumsums as :func:`repro.core.sweep.sbm_count`; their
+  exclusive scan is the offset table.  Each output slot finds its emitter
+  and its rank in it, then one gather per slot reads the counterpart
+  (DESIGN.md §3).  Large buffers expand the offset table over the slots by
+  a scatter and a prefix scan; small ones binary-search it per slot, which
+  is cheaper there (:func:`_slot_map`).  :func:`sbm_enumerate_sharded`
+  runs the scheme, with the search, across a device mesh axis;
+  :func:`repro.kernels.sbm_enumerate_kernel` is the Pallas on-chip form.
 * :func:`enumerate_matches` — blocked all-pairs O(n·m) + stream compaction.
   Kept as the cross-check oracle and for tiny inputs where the sort
   dominates.
@@ -24,6 +27,7 @@ still counted — callers check ``count <= max_pairs`` and retry bigger.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -95,25 +99,108 @@ def _sbm_enumerate_jit(subs: Extents, upds: Extents, *, max_pairs: int,
         off = _offset_cumsum(counts)
         k_total = off[-1]
 
-    # Slot-parallel emission: slot s belongs to the emitter whose offset
-    # range contains it; its rank within the emitter selects the counterpart
-    # by lower-endpoint rank (a contiguous range — see emission_rank_tables).
+    pairs = _emit_pairs(off, counts, k_total, a_start, b_start, subs_by_lo,
+                        upds_by_lo, max_pairs=max_pairs,
+                        num_segments=num_segments,
+                        form=_slot_map(max_pairs, n + m))
+    return pairs, k_total
+
+
+def _emit_pairs(off, counts, k_total, a_start, b_start, subs_by_lo,
+                upds_by_lo, *, max_pairs: int, num_segments: int,
+                form: str) -> jax.Array:
+    """Slot-parallel emission from the offset and rank tables.
+
+    Slot s belongs to the emitter whose offset range contains it; its rank
+    within the emitter selects the counterpart by lower-endpoint rank (a
+    contiguous range — see emission_rank_tables).  ``form`` is how slots
+    find their emitter (:func:`_slot_map`); both give the same buffer.
+    """
+    n = a_start.shape[0]
+    m = b_start.shape[0]
     with jax.named_scope("ddm.search"):
         slots = jnp.arange(max_pairs, dtype=jnp.int32)
-        e = jnp.searchsorted(off, slots, side="right").astype(jnp.int32)
-        e = jnp.minimum(e, n + m - 1)
+    if form == "expand":
+        # One rank→id table: a subscription emitter's counterparts are
+        # upds_by_lo[a_start + r], an update emitter's subs_by_lo[b_start + r]
+        # = table[m + b_start + r]; with r = s - excl, each emitter's base
+        # makes the counterpart table[base[e(s)] + s].
+        with jax.named_scope("ddm.search"):
+            excl = off - counts
+            base = jnp.concatenate([a_start, m + b_start]) - excl
+        e, base_s = _expand_slots(excl, base, max_pairs, num_segments)
+        with jax.named_scope("ddm.gather"):
+            table = jnp.concatenate([upds_by_lo, subs_by_lo])
+            c = table[jnp.minimum(base_s + slots, n + m - 1)]
+            is_a = e < n
+            pi = jnp.where(is_a, e, c)
+            pj = jnp.where(is_a, c, e - n)
+    else:
+        e, r = _search_slots(slots, off, counts)
+        with jax.named_scope("ddm.gather"):
+            is_a = e < n
+            j_of_a = upds_by_lo[jnp.clip(a_start[jnp.minimum(e, n - 1)] + r,
+                                         0, m - 1)]
+            i_of_b = subs_by_lo[jnp.clip(b_start[jnp.clip(e - n, 0, m - 1)]
+                                         + r, 0, n - 1)]
+            pi = jnp.where(is_a, e, i_of_b)
+            pj = jnp.where(is_a, j_of_a, e - n)
     with jax.named_scope("ddm.gather"):
-        r = slots - (off[e] - counts[e])
-        is_a = e < n
-        j_of_a = upds_by_lo[jnp.clip(a_start[jnp.minimum(e, n - 1)] + r,
-                                     0, m - 1)]
-        i_of_b = subs_by_lo[jnp.clip(b_start[jnp.clip(e - n, 0, m - 1)] + r,
-                                     0, n - 1)]
-        pi = jnp.where(is_a, e, i_of_b)
-        pj = jnp.where(is_a, j_of_a, e - n)
         valid = slots < jnp.minimum(k_total, max_pairs)
-        pairs = jnp.where(valid[:, None], jnp.stack([pi, pj], axis=-1), -1)
-    return pairs, k_total
+        return jnp.where(valid[:, None], jnp.stack([pi, pj], axis=-1), -1)
+
+
+def _slot_map(max_pairs: int, n_emitters: int) -> str:
+    """How the emission maps its output slots to emitters, from the shapes.
+
+    ``"search"`` binary-searches the offset table for every slot: about
+    ⌈log2(n+m)⌉ dependent probes a slot, then a gather per table.
+    ``"expand"`` scatters two marks per emitter and scans the slots: about
+    2·(n+m) scattered updates, one pass over the slots, one gather.  The
+    expansion is taken where the search's probes outnumber its updates.
+    """
+    probes = max_pairs * math.ceil(math.log2(n_emitters))
+    return "expand" if probes > 2 * n_emitters else "search"
+
+
+def _search_slots(slots: jax.Array, off: jax.Array, counts: jax.Array):
+    """Emitter of every slot and the slot's rank within it, by binary
+    search of the inclusive offset table ``off``."""
+    with jax.named_scope("ddm.search"):
+        e = jnp.searchsorted(off, slots, side="right").astype(jnp.int32)
+        e = jnp.minimum(e, off.shape[0] - 1)
+    with jax.named_scope("ddm.gather"):
+        return e, slots - (off[e] - counts[e])
+
+
+def _expand_slots(excl: jax.Array, base: jax.Array, max_pairs: int,
+                  num_segments: int):
+    """Emitter of every slot and that emitter's ``base``, by run-length
+    expansion: no per-slot search and no per-emitter gather.
+
+    Emitter e marks the first slot of its range, ``excl[e]``, with 1 and with
+    the step ``base[e] - base[e-1]``; an inclusive scan over the slots then
+    gives slot s the number of emitters starting at or before it, e(s) + 1,
+    and the telescoped sum ``base[e(s)]``.  Emitters with nothing to emit
+    share the next one's start and cancel out; emitters starting at or past
+    ``max_pairs`` own no slot and are dropped.  ``excl`` is non-decreasing
+    below ``max_pairs``; ``base`` of every emitter that owns a slot fits
+    int32, and the int32 sums wrap harmlessly (the result fits).  The scans
+    are two-level scans over ``gcd(max_pairs, num_segments)`` segments,
+    which measured faster on a v5e than one ``jnp.cumsum``.
+    """
+    with jax.named_scope("ddm.search"):
+        at = jnp.minimum(excl, max_pairs).astype(jnp.int32)
+        base = base.astype(jnp.int32)
+        step = base - jnp.concatenate([jnp.zeros((1,), jnp.int32), base[:-1]])
+
+        def scan(marks):
+            return prefix_lib.cumsum_two_level(
+                jnp.zeros((max_pairs,), jnp.int32).at[at].add(
+                    marks, mode="drop"),
+                math.gcd(max_pairs, num_segments))
+
+        return scan(jnp.ones_like(at)) - 1, scan(step)
 
 
 def sbm_enumerate(subs: Extents, upds: Extents, *, max_pairs: int,
@@ -121,9 +208,12 @@ def sbm_enumerate(subs: Extents, upds: Extents, *, max_pairs: int,
                   ) -> Tuple[jax.Array, jax.Array]:
     """All matching (i, j) pairs via the sort-based sweep (1-d extents).
 
-    Output-sensitive O((n+m)·log(n+m) + K): no n×m intermediate is ever
-    formed.  Returns (pairs (max_pairs, 2) int32 padded with (-1, -1),
-    count) with the same overflow contract as :func:`enumerate_matches`.
+    O((n+m)·log(n+m) + max_pairs), one gather per output slot: no n×m
+    intermediate is ever formed.  Slots find their emitters by a scatter
+    and prefix scan over the buffer, or, where the buffer is small against
+    n+m, by binary search (:func:`_slot_map`); both give the same buffer.
+    Returns (pairs (max_pairs, 2) int32 padded with (-1, -1), count) with
+    the same overflow contract as :func:`enumerate_matches`.
     Deterministic order: subscription emitters by id, then update emitters
     by id, each range ordered by the counterpart's lower-endpoint rank.
     Requires well-formed extents (lo <= hi) — like :func:`sbm_count`.
@@ -145,8 +235,10 @@ def sbm_enumerate_planned(subs: Extents, upds: Extents, *,
     Runs the counting sweep as the planner's selectivity probe, sizes
     ``max_pairs`` to the exact K's ladder bucket, and executes the
     emission under the runtime's retry loop (structurally zero retries:
-    the probe count is exact).  Returns ``(pairs, count, stats)`` — the
-    production face of :func:`sbm_enumerate` (DESIGN.md §10).
+    the probe count is exact).  ``stats.regime`` names the slot map the
+    emission took, ``"expand"`` or ``"search"`` (:func:`_slot_map`).
+    Returns ``(pairs, count, stats)`` — the production face of
+    :func:`sbm_enumerate` (DESIGN.md §10).
     """
     from repro.core.sweep import probe_count
 
@@ -162,6 +254,7 @@ def sbm_enumerate_planned(subs: Extents, upds: Extents, *,
                         scan_impl=scan_impl)
 
     def fn(s, u, *, max_pairs):
+        stats.regime = _slot_map(max_pairs, s.lo.shape[0] + u.lo.shape[0])
         return sbm_enumerate(s, u, max_pairs=max_pairs,
                              num_segments=num_segments, scan_impl=scan_impl)
 

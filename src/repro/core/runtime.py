@@ -234,7 +234,8 @@ class MatchStats:
     ``engine`` names the entry point (``"sweep"``, ``"service_rebuild"``,
     ``"incremental_bulk"``, …); ``regime`` the internal strategy when one
     was selected (the bulk rematch's ``dense``/``jax``/``sort``, the
-    ddim generator choice, …).  ``attempts`` lists every capacity tried —
+    sweep emission's ``expand``/``search`` slot map, the ddim generator
+    choice, …).  ``attempts`` lists every capacity tried —
     ``len(attempts) - 1 == retries``.  ``phase_seconds`` keys follow the
     module-level vocabulary (``probe``/``emit``/``collect``; host-side
     engines use their own phase names, e.g. ``rematch``, plus the

@@ -16,8 +16,13 @@ from repro.core import (
     make_uniform_workload,
     sbm_enumerate,
 )
-from repro.core.enumerate import enumerate_matches_sweep_numpy
-from repro.core.sweep import sequential_sbm_pairs_numpy
+from repro.core import enumerate as enumerate_mod
+from repro.core.enumerate import (_emit_pairs, _expand_slots, _offset_cumsum,
+                                  _sbm_enumerate_jit, _search_slots,
+                                  enumerate_matches_sweep_numpy)
+from repro.core.sweep import (_pad_stream, emission_rank_tables,
+                              encode_endpoints, resolve_cumsum,
+                              sequential_sbm_pairs_numpy)
 from repro.kernels import sbm_enumerate_kernel
 
 jax.config.update("jax_platform_name", "cpu")
@@ -218,3 +223,116 @@ if HAVE_HYPOTHESIS:
                                      num_segments=4)
         assert int(count) == len(want)
         assert _pset(pairs) == want
+
+
+# ---------------------------------------------------------------------------
+# slot maps: binary search against expansion by scatter and prefix scan
+# ---------------------------------------------------------------------------
+
+def _synthetic_tables(a_cnt, b_cnt, seed=0):
+    """Offset and rank tables with the given per-emitter counts: each
+    emitter's range start drawn so its counterpart ranks stay inside the
+    table, the rank→id tables random permutations."""
+    n, m = len(a_cnt), len(b_cnt)
+    rng = np.random.RandomState(seed)
+    a_start = jnp.asarray([rng.randint(0, m - c + 1) for c in a_cnt],
+                          jnp.int32)
+    b_start = jnp.asarray([rng.randint(0, n - c + 1) for c in b_cnt],
+                          jnp.int32)
+    counts = jnp.asarray(list(a_cnt) + list(b_cnt), jnp.int32)
+    off = _offset_cumsum(counts)
+    return (off, counts, off[-1], a_start, b_start,
+            jnp.asarray(rng.permutation(n), jnp.int32),
+            jnp.asarray(rng.permutation(m), jnp.int32))
+
+
+def _workload_tables(subs, upds):
+    """The program's own offset and rank tables of an input."""
+    n, m = subs.lo.shape[0], upds.lo.shape[0]
+    ep = _pad_stream(encode_endpoints(subs, upds), 8)
+    a_start, a_cnt, b_start, b_cnt, subs_by_lo, upds_by_lo = \
+        emission_rank_tables(ep, n, m, resolve_cumsum("two_level", 8))
+    counts = jnp.concatenate([a_cnt, b_cnt])
+    off = _offset_cumsum(counts)
+    return off, counts, off[-1], a_start, b_start, subs_by_lo, upds_by_lo
+
+
+SLOT_MAP_CASES = {
+    # name: (a_cnt, b_cnt, max_pairs) or (workload, max_pairs)
+    "zero_runs_lead_inner_trail": ([0, 0, 3, 0, 0, 2, 0],
+                                   [0, 1, 0, 0, 4, 0, 0], 16),
+    "k_zero": ([0, 0, 0], [0, 0], 8),
+    "k_equals_max_pairs": ([2, 0, 3, 1], [0, 2, 0, 0], 8),
+    "k_beyond_max_pairs": ([3, 4, 0, 2], [4, 0, 1, 3], 6),
+    "one_emitter_owns_every_slot": ([0, 0, 5, 0], [0, 0, 0, 0, 0], 5),
+    "one_emitter_overflows": ([0, 0, 5, 0], [0, 0, 0, 0, 0], 3),
+    "n_is_one": ([3], [0, 1, 0], 4),
+    "m_is_one": ([1, 0, 1, 1], [3], 8),
+    "ties": (lambda: _mk([0, 2, 2, 4, 4], [2, 4, 4, 6, 6],
+                         [2, 2, 4, 0], [2, 4, 4, 6]), 32),
+    "duplicates": (lambda: _mk([1.0] * 9, [2.0] * 9, [1.5] * 7, [3.0] * 7),
+                   64),
+    "duplicates_overflow": (lambda: _mk([1.0] * 9, [2.0] * 9,
+                                        [1.5] * 7, [3.0] * 7), 40),
+}
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
+@pytest.mark.parametrize("case", sorted(SLOT_MAP_CASES))
+def test_slot_maps_are_bit_identical(case, x64):
+    """The expansion gives every slot the search's emitter and counterpart
+    rank, and the same padded buffer, on the same offset tables."""
+    spec = SLOT_MAP_CASES[case]
+    with jax.enable_x64(x64):
+        if callable(spec[0]):
+            tables = _workload_tables(*spec[0]())
+        else:
+            tables = _synthetic_tables(*spec[:2])
+        max_pairs = spec[-1]
+        off, counts, k_total, a_start, b_start = tables[:5]
+        n, m = a_start.shape[0], b_start.shape[0]
+        slots = jnp.arange(max_pairs, dtype=jnp.int32)
+
+        e_search, r = _search_slots(slots, off, counts)
+        starts = jnp.concatenate([a_start, m + b_start])
+        e_expand, base = _expand_slots(off - counts, starts - (off - counts),
+                                       max_pairs, 8)
+        np.testing.assert_array_equal(e_expand, e_search)
+        np.testing.assert_array_equal(base + slots, starts[e_search] + r)
+
+        search, expand = (_emit_pairs(*tables, max_pairs=max_pairs,
+                                      num_segments=8, form=f)
+                          for f in ("search", "expand"))
+        assert search.dtype == expand.dtype == jnp.int32
+        np.testing.assert_array_equal(expand, search)
+        k = min(int(k_total), max_pairs)
+        got = np.asarray(search)
+        assert np.all(got[k:] == -1) and np.all(got[:k] >= 0)
+        assert np.all(got[:k, 0] < n) and np.all(got[:k, 1] < m)
+
+
+@pytest.mark.parametrize("max_pairs,form", [(64, "search"), (1024, "expand")])
+def test_enumerate_rows_equal_the_search_rows(monkeypatch, max_pairs, form):
+    """The program takes the rule's slot map and returns, row for row, what
+    it returns with the search forced."""
+    subs, upds = make_uniform_workload(jax.random.PRNGKey(11), 300, 400,
+                                       alpha=2.0, length=1000.0)
+    assert enumerate_mod._slot_map(max_pairs, 700) == form
+    static = dict(max_pairs=max_pairs, num_segments=8, scan_impl="two_level")
+    pairs, count = _sbm_enumerate_jit(subs, upds, **static)
+    monkeypatch.setattr(enumerate_mod, "_slot_map", lambda *_: "search")
+    searched = jax.jit(_sbm_enumerate_jit.__wrapped__,
+                       static_argnames=tuple(static))
+    want_pairs, want_count = searched(subs, upds, **static)
+    assert int(count) == int(want_count) > max_pairs // 2
+    np.testing.assert_array_equal(pairs, want_pairs)
+
+
+@pytest.mark.parametrize("max_pairs,n_emitters,form", [
+    (64, 700, "search"), (140, 700, "search"), (141, 700, "expand"),
+    (1024, 700, "expand"), (4, 2, "search"), (5, 2, "expand"),
+    (8192, 10**6, "search"), (1 << 26, 10**6, "expand")])
+def test_slot_map_rule_counts_work(max_pairs, n_emitters, form):
+    """Expand where the search's max_pairs·⌈log2(n+m)⌉ probes outnumber
+    the expansion's 2·(n+m) scattered marks."""
+    assert enumerate_mod._slot_map(max_pairs, n_emitters) == form

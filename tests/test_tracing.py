@@ -18,7 +18,8 @@ import jax
 import pytest
 
 from repro.core import make_uniform_workload, runtime
-from repro.core.enumerate import _sbm_enumerate_jit, sbm_enumerate_planned
+from repro.core.enumerate import (_sbm_enumerate_jit, _slot_map,
+                                  sbm_enumerate_planned)
 from repro.core.sweep import _sbm_count_partials
 
 jax.config.update("jax_platform_name", "cpu")
@@ -31,13 +32,18 @@ def _workload(seed=0):
     return make_uniform_workload(jax.random.PRNGKey(seed), 300, 400, 2.0)
 
 
-# (name, jitted function, static arguments) of the planned sweep's programs
+# (name, jitted function, static arguments) of the planned sweep's programs;
+# with n+m = 700 the emission expands 1024 slots and searches for 64
 PROGRAMS = [
     ("count", _sbm_count_partials,
      dict(num_segments=8, scan_impl="two_level")),
     ("emit", _sbm_enumerate_jit,
      dict(max_pairs=1024, num_segments=8, scan_impl="two_level")),
+    ("emit_search", _sbm_enumerate_jit,
+     dict(max_pairs=64, num_segments=8, scan_impl="two_level")),
 ]
+# the loops each program keeps: only the binary search is a while
+LOOPS = {"count": {"sort"}, "emit": {"sort"}, "emit_search": {"sort", "while"}}
 
 
 def _instructions(hlo: str):
@@ -145,6 +151,15 @@ def test_planned_call_makes_five_readbacks():
     assert stats.readbacks == 5      # four count partials, then the count
 
 
+@pytest.mark.parametrize("alpha,regime", [(0.1, "search"), (2.0, "expand")])
+def test_planned_call_names_its_slot_map(alpha, regime):
+    """``stats.regime`` is the slot map the rule gives the planned buffer."""
+    subs, upds = make_uniform_workload(jax.random.PRNGKey(4), 300, 400, alpha)
+    _, _, stats = sbm_enumerate_planned(subs, upds)
+    assert stats.regime == _slot_map(stats.capacity, 700) == regime
+    assert stats.as_dict()["regime"] == regime
+
+
 def test_planned_call_spans_on_the_profiler_clock(tmp_path):
     from jax.profiler import ProfileData
 
@@ -184,7 +199,8 @@ def test_every_stage_is_named_in_the_compiled_programs():
     """Each program's stages appear in its optimized HLO's ``op_name``s,
     and no code of the program lies outside a stage: every instruction
     whose ``op_name`` is rooted at the program (``jit(...)/...``) has a
-    ``ddm.*`` component, and so has every sort and while.  (A fusion the
+    ``ddm.*`` component, and so has every sort and while, in the emission's
+    search and expansion forms alike.  (A fusion the
     CPU backend makes around a single pad or reduce-window carries no
     ``op_name``; one inside a comparator or fused computation carries a
     name relative to it, like ``or``.)"""
@@ -201,8 +217,7 @@ def test_every_stage_is_named_in_the_compiled_programs():
                   if op and op.startswith("jit(") and not _scope(op)]
         assert not rooted, (name, rooted)
         loops = [(opc, op) for opc, op in insts if opc in ("sort", "while")]
-        assert {opc for opc, _ in loops} == (
-            {"sort"} if name == "count" else {"sort", "while"}), name
+        assert {opc for opc, _ in loops} == LOOPS[name], name
         assert all(_scope(op) for _, op in loops), (name, loops)
     assert seen == PROBE_SCOPES | EMIT_SCOPES
 
