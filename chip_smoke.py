@@ -2,7 +2,7 @@
 """Drive the DDM system's main path once on a TPU and check every result.
 
     python3 chip_smoke.py [--seed N]                # one chip, every phase
-    python3 chip_smoke.py [--seed N] --four-chips   # only the sharded engines
+    python3 chip_smoke.py [--seed N] --four-chips   # only the mesh paths
 
 One process, no children.  Each phase prints one line: its name, its sizes,
 its wall time in this run, and what it was checked against.  Phases:
@@ -20,9 +20,9 @@ its wall time in this run, and what it was checked against.  Phases:
   kernels  the Pallas kernels compiled for the chip (interpret=False)
            against the XLA engines
 
-``--four-chips`` runs only the sharded engines (sbm_count_sharded,
-sbm_enumerate_sharded, bitmatrix_sharded) over a mesh of four chips and
-compares them with the one-chip engines.
+``--four-chips`` runs only the paths over a mesh of four chips: the
+planned sweep (``sbm_enumerate_planned(..., mesh=mesh)``) on float32 and
+int32 sets at N = 10⁶, and bitmatrix_sharded, each against one chip.
 
 Any mismatch or exception exits non-zero.  The last line of stdout, on
 success only, is ``{"ok": true, "device": {"platform", "kind", "count"}}``.
@@ -358,10 +358,22 @@ def kernels_phase(seed: int) -> None:
            "XLA sbm_count/sbm_enumerate and bitmatrix_words/_enumerate")
 
 
+def hla_int_set(seed: int):
+    """The paper's uniform α = 1 placement on HLA's integer dimension:
+    N = 10⁶ int32 extents of length 10 on [0, 10N]."""
+    from repro.core import Extents
+
+    rng = np.random.default_rng(seed)
+    length, seg = 10 * PAPER_N, 10
+    lo = jax.numpy.asarray(rng.integers(0, length - seg + 1, PAPER_N),
+                           jax.numpy.int32)
+    n = PAPER_N // 2
+    return (Extents(lo[:n], lo[:n] + seg), Extents(lo[n:], lo[n:] + seg))
+
+
 def four_chip_phase(seed: int) -> None:
     from repro.core import bitmatrix_count, bitmatrix_sharded, \
-        bitmatrix_words, sbm_count, sbm_count_sharded, sbm_enumerate, \
-        sbm_enumerate_sharded
+        bitmatrix_words, sbm_enumerate_planned
 
     t0 = start()
     mesh = jax.make_mesh((4,), ("p",), devices=jax.devices()[:4])
@@ -372,20 +384,22 @@ def four_chip_phase(seed: int) -> None:
         check(not x.sharding.is_fully_replicated,
               f"{what} is copied whole to every device, not sharded")
 
-    subs, upds = paper_set(seed, "uniform", 1.0)
-    k = int(sbm_count(subs, upds))
-    k4 = sbm_count_sharded(subs, upds, mesh, "p")
-    check(len(k4.sharding.device_set) == 4,
-          "sbm_count_sharded ran on fewer than 4 devices")
-    check(int(k4) == k, f"sbm_count_sharded K={int(k4)}, one chip K={k}")
-    pairs4, count4 = sbm_enumerate_sharded(subs, upds, mesh, "p",
-                                           max_pairs=k)
-    spread(pairs4, "sbm_enumerate_sharded pairs")
-    pairs1, _ = sbm_enumerate(subs, upds, max_pairs=k)
-    check(int(count4) == k, f"sbm_enumerate_sharded count {int(count4)}")
-    check(np.array_equal(pair_keys(pairs4, upds.size),
-                         pair_keys(pairs1, upds.size)),
-          "sbm_enumerate_sharded pairs differ from one chip")
+    ks = {}
+    for dtype, (subs, upds) in (("float32", paper_set(seed, "uniform", 1.0)),
+                                ("int32", hla_int_set(seed))):
+        pairs1, count1, _ = sbm_enumerate_planned(subs, upds)
+        pairs4, count4, stats = sbm_enumerate_planned(subs, upds, mesh=mesh)
+        spread(pairs4, f"{dtype} planned pairs on the mesh")
+        check(stats.chips == 4 and stats.exchange_bytes > 0,
+              f"{dtype} planned call on the mesh: chips {stats.chips}, "
+              f"exchange bytes {stats.exchange_bytes}")
+        check(int(count4) == int(count1),
+              f"{dtype} planned count on the mesh {int(count4)}, one chip "
+              f"{int(count1)}")
+        check(np.array_equal(pair_keys(pairs4, upds.size),
+                             pair_keys(pairs1, upds.size)),
+              f"{dtype} planned pairs on the mesh differ from one chip")
+        ks[dtype] = int(count1)
 
     subs2, upds2 = tall_thin_set(seed, DDIM_N, 1.0)
     words4, kw4 = bitmatrix_sharded(subs2, upds2, mesh, "p")
@@ -397,16 +411,17 @@ def four_chip_phase(seed: int) -> None:
     kw1 = int(bitmatrix_count(subs2, upds2))
     check(int(kw4) == kw1, f"bitmatrix_sharded K={int(kw4)}, one chip {kw1}")
     report("4-chip", t0,
-           f"mesh (4,) sweep N={PAPER_N} α=1 K={k}; bitmatrix d=2 "
+           f"mesh (4,) planned sweep N={PAPER_N} α=1 float32 K="
+           f"{ks['float32']}, int32 K={ks['int32']}; bitmatrix d=2 "
            f"n=m={DDIM_N} K={kw1}",
-           "one-chip sbm_count, sbm_enumerate and bitmatrix_words")
+           "one-chip sbm_enumerate_planned and bitmatrix_words")
 
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--four-chips", action="store_true",
-                    help="run only the sharded engines over four chips")
+                    help="run only the mesh paths over four chips")
     args = ap.parse_args()
 
     from repro.compile_cache import use_compile_cache

@@ -39,7 +39,9 @@ from repro.core import prefix as prefix_lib
 from repro.core import runtime as runtime_lib
 from repro.core.intervals import Extents, intersect_1d
 from repro.core.runtime import round_up_pow2  # noqa: F401 — canonical ladder
-from repro.core.sweep import (_indicator_deltas, _pad_stream,
+from repro.core.errors import ValidationError
+from repro.core.sweep import (_lane_partial_sums, _pad_stream,
+                              _saturate_from_lanes, _decode_tags,
                               emission_rank_tables, encode_endpoints,
                               rank_tables_from_cumsums, resolve_cumsum)
 
@@ -229,7 +231,8 @@ def sbm_enumerate_planned(subs: Extents, upds: Extents, *,
                           scan_impl: str = "two_level",
                           policy: runtime_lib.CapacityPolicy =
                           runtime_lib.DEFAULT_POLICY,
-                          recorder: runtime_lib.StatsRecorder | None = None):
+                          recorder: runtime_lib.StatsRecorder | None = None,
+                          mesh=None):
     """Plan-aware sweep enumeration: probe → plan → emit, instrumented.
 
     Runs the counting sweep as the planner's selectivity probe, sizes
@@ -239,8 +242,18 @@ def sbm_enumerate_planned(subs: Extents, upds: Extents, *,
     emission took, ``"expand"`` or ``"search"`` (:func:`_slot_map`).
     Returns ``(pairs, count, stats)`` — the production face of
     :func:`sbm_enumerate` (DESIGN.md §10).
+
+    With a one-axis ``mesh`` the sets (best sharded over its axis) are
+    matched across its chips: the probe sorts the endpoint stream across
+    the mesh and counts (:func:`repro.core.sweep._sort_count_sharded`), the
+    emission reuses that sorted stream, and the pair buffer comes back
+    sharded over the axis, its rows rounded up to a multiple of the chip
+    count (DESIGN.md §14).  ``stats.chips`` and ``stats.exchange_bytes``
+    record the mesh's share.
     """
-    from repro.core.sweep import probe_count
+    from repro.core.sweep import (_exact_count, probe_count,
+                                  _sort_count_exchange_bytes,
+                                  _sort_count_sharded)
 
     stats = runtime_lib.MatchStats(engine="sweep")
     if subs.size == 0 or upds.size == 0:
@@ -249,14 +262,37 @@ def sbm_enumerate_planned(subs: Extents, upds: Extents, *,
             recorder.record(stats)
         return jnp.full((0, 2), -1, jnp.int32), jnp.int32(0), stats
 
-    with stats.phase("probe"):
-        k = probe_count(subs, upds, stats, num_segments=num_segments,
-                        scan_impl=scan_impl)
+    if mesh is None:
+        with stats.phase("probe"):
+            k = probe_count(subs, upds, stats, num_segments=num_segments,
+                            scan_impl=scan_impl)
 
-    def fn(s, u, *, max_pairs):
-        stats.regime = _slot_map(max_pairs, s.lo.shape[0] + u.lo.shape[0])
-        return sbm_enumerate(s, u, max_pairs=max_pairs,
-                             num_segments=num_segments, scan_impl=scan_impl)
+        def fn(s, u, *, max_pairs):
+            stats.regime = _slot_map(max_pairs,
+                                     s.lo.shape[0] + u.lo.shape[0])
+            return sbm_enumerate(s, u, max_pairs=max_pairs,
+                                 num_segments=num_segments,
+                                 scan_impl=scan_impl)
+    else:
+        if len(mesh.axis_names) != 1:
+            raise ValidationError(f"a planned sweep spans one mesh axis, "
+                                  f"not {mesh.axis_names}")
+        axis, = mesh.axis_names
+        n, m, chips = subs.size, upds.size, mesh.size
+        stats.chips = chips
+        with stats.phase("probe"):
+            tags, partials = _sort_count_sharded(subs, upds, mesh=mesh,
+                                                 axis_name=axis)
+            with stats.readback("probe", len(partials)):
+                k = _exact_count(*partials)
+        stats.exchange_bytes += _sort_count_exchange_bytes(n, m, chips)
+
+        def fn(s, u, *, max_pairs):
+            stats.regime = _slot_map(max_pairs, tags.shape[0] // chips)
+            stats.exchange_bytes += _emit_exchange_bytes(
+                n, m, chips, max_pairs, stats.regime)
+            return _emit_sharded(tags, n=n, m=m, max_pairs=max_pairs,
+                                 mesh=mesh, axis_name=axis)
 
     return runtime_lib.execute_enumeration(
         fn, subs, upds, estimate=k, policy=policy, stats=stats,
@@ -264,77 +300,92 @@ def sbm_enumerate_planned(subs: Extents, upds: Extents, *,
 
 
 def sbm_enumerate_sharded(subs: Extents, upds: Extents, mesh, axis_name: str,
-                          *, max_pairs: int,
-                          max_pairs_per_shard: int | None = None
-                          ) -> Tuple[jax.Array, jax.Array]:
-    """Distributed sweep enumeration over one mesh axis.
+                          *, max_pairs: int) -> Tuple[jax.Array, jax.Array]:
+    """Distributed sweep enumeration over one mesh axis: the sort across
+    the mesh (:func:`repro.core.sweep._sort_count_sharded`), then the
+    emission from that stream, each chip writing only the slots it owns
+    (DESIGN.md §14) — the two programs a planned sweep on a mesh runs.
 
-    Mirrors :func:`repro.core.sweep.sbm_count_sharded`: the sorted stream is
-    split into contiguous shards, global indicator cumsums run as the
-    distributed two-level scan, and each shard emits the pairs whose
-    emitting upper endpoint it owns.  Global pair offsets are the
-    psum'd/all-gathered per-shard emission totals.  The output buffer is
-    sharded over ``axis_name`` in equal slot ranges: each shard emits the
-    pairs of its global range that fall in every destination's slots and
-    one ``all_to_all`` delivers them, so a chip holds O(max_pairs) pair
-    slots in flight and keeps max_pairs / P of the result.  The rank→id
-    tables are psum-combined (O(n+m) comm — the pair payload itself is the
-    dominant output).
-
-    The buffer has ``max_pairs`` rounded up to a positive multiple of the
-    shard count rows (an uneven row sharding does not exist); rows past
-    ``min(count, max_pairs)`` are −1.  A shard keeps at most
-    ``max_pairs_per_shard`` (default ``max_pairs``) of its pairs and drops
-    the excess, but the returned count is still exact.  Without x64, a
-    global K ≥ 2³¹ pins the count at the 2³¹−1 sentinel and returns an
-    all-(-1) buffer (the cross-shard offsets would wrap) — never silently
-    wrong pairs.
+    Returns ``(pairs, count)``: the buffer has ``max_pairs`` rounded up to
+    a positive multiple of the shard count rows, sharded over
+    ``axis_name``; rows past ``min(count, max_pairs)`` are −1.  Without
+    x64, a global K ≥ 2³¹ pins the count at the 2³¹−1 sentinel and
+    returns an all-(-1) buffer (the cross-shard offsets would wrap) —
+    never silently wrong pairs.
     """
-    from jax.sharding import PartitionSpec as P
+    from repro.core.sweep import _sort_count_sharded
 
-    n = subs.lo.shape[0]
-    m = upds.lo.shape[0]
+    n, m = subs.lo.shape[0], upds.lo.shape[0]
     if n == 0 or m == 0:
         return _empty_result(max_pairs)
+    tags, _ = _sort_count_sharded(subs, upds, mesh=mesh, axis_name=axis_name)
+    return _emit_sharded(tags, n=n, m=m, max_pairs=max_pairs, mesh=mesh,
+                         axis_name=axis_name)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "m", "max_pairs", "mesh",
+                                             "axis_name"))
+def _emit_sharded(tags: jax.Array, *, n: int, m: int, max_pairs: int, mesh,
+                  axis_name: str):
+    """The emission from a stream sorted across the mesh: the pair buffer
+    (sharded over the axis, ``per_shard`` rows a shard) and the count."""
+    from jax.sharding import PartitionSpec as P
+
+    shards = mesh.shape[axis_name]
+    per_shard = -(-max(max_pairs, 1) // shards)
+    fn = jax.shard_map(
+        functools.partial(
+            _emit_shard_body, n=n, m=m, max_pairs=max_pairs,
+            per_shard=per_shard, shards=shards, axis_name=axis_name,
+            form=_slot_map(max_pairs, tags.shape[0] // shards)),
+        mesh=mesh, in_specs=P(axis_name), out_specs=(P(axis_name), P()),
+        check_vma=False)
+    return fn(tags)
+
+
+def _emit_shard_body(tags, *, n, m, max_pairs, per_shard, shards, axis_name,
+                     form):
+    """Shard body of the emission; ``tags`` is this shard's contiguous
+    range of the sorted stream.
+
+    The rank tables are the single-chip construction over the shard's
+    records, psum'd into whole (n,)/(m,) tables on every shard.  Every
+    stream position is an emitter: an upper endpoint emits its extent's
+    class count, any other record nothing, and emitters in stream order
+    own consecutive global slots from their shard's exclusive offset.
+    Shard q owns output slots ``[q·per_shard, (q+1)·per_shard)``.
+    """
     cdtype = _count_dtype()
-    cap = max_pairs if max_pairs_per_shard is None else max_pairs_per_shard
-    num_shards = mesh.shape[axis_name]
-    per_shard = -(-max(max_pairs, 1) // num_shards)  # output slots per shard
-    ep = _pad_stream(encode_endpoints(subs, upds), num_shards)
-    sub_lo, sub_up, upd_lo, upd_up = _indicator_deltas(ep)
-    owner = ep.owner
-    is_upper = ep.is_upper.astype(jnp.int32)
-    is_sub = ep.is_sub.astype(jnp.int32)
-
-    def body(sub_lo, upd_lo, owner, is_upper, is_sub):
-        # Stream-position cumsums are bounded by the stream length and
-        # always fit int32 (unlike the pair counts below); pin the dtype so
-        # the rank-table scatters stay int32 under x64.
-        c_sub_lo = prefix_lib.shard_inclusive_cumsum(
-            sub_lo, axis_name).astype(jnp.int32)
-        c_upd_lo = prefix_lib.shard_inclusive_cumsum(
-            upd_lo, axis_name).astype(jnp.int32)
-
-        # Rank tables: the same class-A/B construction as the single-device
-        # path; each extent's endpoints live on some shard, so the psum
-        # combine assembles the full (n,)/(m,) tables on every shard.
-        a_start, a_cnt, b_start, b_cnt, subs_by_lo, upds_by_lo = \
-            rank_tables_from_cumsums(
-                is_sub == 1, is_upper == 1, owner, c_sub_lo, c_upd_lo, n, m,
-                combine=lambda t: lax.psum(t, axis_name))
-
-        # local emission: one count per local upper endpoint (the emitter's
-        # class count, gathered from the global tables at its owner)
+    slots_all = shards * per_shard
+    with jax.named_scope("ddm.ranks"):
+        is_sub, is_upper, owner = _decode_tags(tags, n, m)
         real = owner >= 0
-        sel_s_up = (is_sub == 1) & (is_upper == 1) & real
-        sel_u_up = (is_sub == 0) & (is_upper == 1) & real
-        o_c = jnp.clip(owner, 0)
-        cnt = jnp.where(sel_s_up, a_cnt[jnp.minimum(o_c, n - 1)], 0)
-        cnt = cnt + jnp.where(sel_u_up, b_cnt[jnp.minimum(o_c, m - 1)], 0)
-        # per-shard offsets: int64-exact under x64, saturating int32 without
-        # (the aggregate psum'd count is exact only below 2^31 in that case)
-        lc = _offset_cumsum(cnt)
-        local_total = lc[-1]
+        # stream-position cumsums fit int32; pin it under x64 too
+        c_sub_lo = prefix_lib.shard_inclusive_cumsum(
+            (is_sub & ~is_upper & real).astype(jnp.int32),
+            axis_name).astype(jnp.int32)
+        c_upd_lo = prefix_lib.shard_inclusive_cumsum(
+            (~is_sub & ~is_upper & real).astype(jnp.int32),
+            axis_name).astype(jnp.int32)
+        local = rank_tables_from_cumsums(is_sub, is_upper, owner, c_sub_lo,
+                                         c_upd_lo, n, m)
+    with jax.named_scope("ddm.exchange"):
+        # each table is linear in its scattered entries: psum the shards'
+        a_start, a_cnt, b_start, b_cnt, subs_by_lo, upds_by_lo = (
+            lax.psum(t, axis_name) for t in local)
+    with jax.named_scope("ddm.ranks"):
+        sel_s = is_sub & is_upper & real
+        sel_u = ~is_sub & is_upper & real
+        o_s = jnp.where(sel_s, owner, 0)
+        o_u = jnp.where(sel_u, owner, 0)
+        cnt = jnp.where(sel_s, a_cnt[o_s], jnp.where(sel_u, b_cnt[o_u], 0))
+        # the emitter's code (sub i, or n + update j) and where its
+        # counterparts start in table = [upds_by_lo, subs_by_lo]
+        code = jnp.where(sel_s, o_s, n + o_u)
+        start = jnp.where(sel_s, a_start[o_s], m + b_start[o_u])
+        table = jnp.concatenate([upds_by_lo, subs_by_lo])
+        lc, local_total = _shard_offsets(cnt)
+    with jax.named_scope("ddm.exchange"):
         base = prefix_lib.shard_exclusive_offsets(local_total, axis_name)
         if cdtype == jnp.int64:
             k_total = lax.psum(local_total, axis_name)
@@ -342,50 +393,134 @@ def sbm_enumerate_sharded(subs: Extents, upds: Extents, mesh, axis_name: str,
         else:
             # psum of int32 local totals can wrap even when every shard is
             # below the sentinel — combine 15-bit lanes (each psum provably
-            # fits int32 for any realistic shard count) and saturate, so
-            # the aggregate honors the same never-wrap contract as
-            # _offset_cumsum.  When the aggregate does overflow, the
-            # cross-shard offsets (base) would wrap and route pairs to the
-            # wrong output slots, so the overflow
-            # flag blanks the pair buffer: callers get the 2^31-1 count
-            # sentinel and an all-(-1) buffer, never silently wrong pairs.
-            hi = lax.psum(local_total >> 15, axis_name)
+            # fits int32 for any realistic shard count) and saturate.  When
+            # the aggregate overflows, the cross-shard offsets (base) wrap,
+            # so the flag blanks the pair buffer: callers get the 2^31-1
+            # count sentinel and an all-(-1) buffer, never wrong pairs.  A
+            # shard whose own total saturated has wrapped offsets (lc) even
+            # where the others emit nothing: it adds 2^16 to the high lane,
+            # which raises the flag.
+            saturated = local_total == jnp.int32((1 << 31) - 1)
+            hi = lax.psum((local_total >> 15)
+                          + jnp.where(saturated, jnp.int32(1 << 16), 0),
+                          axis_name)
             lo15 = lax.psum(local_total & 0x7FFF, axis_name)
             s = (hi << 15) + lo15
             overflow = (hi >= 1 << 16) | (s < 0)
             k_total = jnp.where(overflow, jnp.int32((1 << 31) - 1), s)
 
-        # global slot g = dest·per_shard + t is this shard's local pair
-        # g − base; row dest of the send buffer goes to shard dest
-        g = (jnp.arange(num_shards, dtype=jnp.int32)[:, None] * per_shard
-             + jnp.arange(per_shard, dtype=jnp.int32)[None, :]).reshape(-1)
-        local = g - base
-        lvalid = ((local >= 0) & (local < jnp.minimum(local_total, cap))
-                  & (g < max_pairs) & ~overflow)
-        slots = jnp.clip(local, 0).astype(jnp.int32)
-        epos = jnp.searchsorted(lc, slots, side="right").astype(jnp.int32)
-        epos = jnp.minimum(epos, lc.shape[0] - 1)
-        r = slots - (lc[epos] - cnt[epos])
-        o = jnp.clip(owner[epos], 0)
-        emitter_is_sub = sel_s_up[epos]
-        j_of_a = upds_by_lo[jnp.clip(a_start[jnp.minimum(o, n - 1)] + r,
-                                     0, m - 1)]
-        i_of_b = subs_by_lo[jnp.clip(b_start[jnp.minimum(o, m - 1)] + r,
-                                     0, n - 1)]
-        pi = jnp.where(emitter_is_sub, o, i_of_b)
-        pj = jnp.where(emitter_is_sub, j_of_a, o)
-        send = jnp.where(lvalid[:, None], jnp.stack([pi, pj], axis=-1), -1)
-        recv = lax.all_to_all(send.reshape(num_shards, per_shard, 2),
-                              axis_name, 0, 0)
+    if form == "expand":
+        with jax.named_scope("ddm.search"):
+            excl = base + lc - cnt
+            rebased = start - excl.astype(jnp.int32)
+        code_s, base_s = _expand_slots_sharded(excl, (code, rebased),
+                                               slots_all, axis_name)
+        with jax.named_scope("ddm.gather"):
+            mine = lax.axis_index(axis_name) * per_shard + jnp.arange(
+                per_shard, dtype=jnp.int32)
+            c = table[jnp.clip(base_s + mine, 0, n + m - 1)]
+            is_a = code_s < n
+            pairs = jnp.stack([jnp.where(is_a, code_s, c),
+                               jnp.where(is_a, c, code_s - n)], axis=-1)
+            valid = (mine < jnp.minimum(k_total, max_pairs)) & ~overflow
+            return jnp.where(valid[:, None], pairs, -1), k_total
+
+    # search: the slots are few; every shard finds the emitter of each of
+    # its own pairs among all slots, and one all_to_all routes the rows
+    with jax.named_scope("ddm.search"):
+        g = jnp.arange(slots_all, dtype=jnp.int32)
+        rank = g - base
+        lvalid = ((rank >= 0) & (rank < local_total) & (g < max_pairs)
+                  & ~overflow)
+        e, r = _search_slots(jnp.clip(rank, 0).astype(jnp.int32), lc, cnt)
+    with jax.named_scope("ddm.gather"):
+        c = table[jnp.clip(start[e] + r, 0, n + m - 1)]
+        is_a = code[e] < n
+        send = jnp.where(lvalid[:, None], jnp.stack(
+            [jnp.where(is_a, code[e], c),
+             jnp.where(is_a, c, code[e] - n)], axis=-1), -1)
+    with jax.named_scope("ddm.exchange"):
+        recv = lax.all_to_all(send.reshape(shards, per_shard, 2), axis_name,
+                              0, 0)
+    with jax.named_scope("ddm.gather"):
         # the shards' global ranges are disjoint: at most one source holds
         # a pair for each slot, the rest send −1
         return jnp.max(recv, axis=0), k_total
 
-    fn = jax.jit(jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(axis_name),) * 5,
-        out_specs=(P(axis_name), P())))
-    return fn(sub_lo, upd_lo, owner, is_upper, is_sub)
+
+def _shard_offsets(cnt: jax.Array):
+    """The inclusive offsets of a shard's emitters and its total, under
+    :func:`_offset_cumsum`'s contract: int64 under x64; without it the
+    total saturates at 2³¹−1 (exact lane sums, :func:`_lane_partial_sums`)
+    and the offsets are a plain int32 scan, which wraps only where the
+    total saturates; the caller then flags overflow and blanks the
+    buffer.  (The saturating tree
+    scan takes the chip's compiler tens of minutes over 10⁷ emitters.)"""
+    if _count_dtype() == jnp.int64:
+        lc = jnp.cumsum(cnt, dtype=jnp.int64)
+        return lc, lc[-1]
+    return (jnp.cumsum(cnt, dtype=jnp.int32),
+            _saturate_from_lanes(*_lane_partial_sums(cnt)))
+
+
+def _expand_slots_sharded(excl, values, slots_all: int, axis_name: str):
+    """Each of this shard's slots' emitter ``values``, by the run-length
+    expansion of :func:`_expand_slots` across the mesh.
+
+    Every stream position marks its first global slot ``excl`` with the
+    step of each value from the position before it in the global order
+    (for the shard's first, the previous shard's last); a reduce-scatter
+    sums the shards' marks into each owner's slot range, and a scan across
+    shards gives every slot the telescoped value of the last position
+    starting at or before it — its emitter, since positions that emit
+    nothing share the next one's start.  int32 sums wrap harmlessly.
+    """
+    with jax.named_scope("ddm.search"):
+        at = jnp.minimum(excl, slots_all).astype(jnp.int32)
+    out = []
+    for v in values:
+        v = v.astype(jnp.int32)
+        with jax.named_scope("ddm.exchange"):
+            last = lax.all_gather(v[-1], axis_name)
+        with jax.named_scope("ddm.search"):
+            i = lax.axis_index(axis_name)
+            prev = jnp.where(i > 0, last[jnp.maximum(i - 1, 0)], 0)
+            step = v - jnp.concatenate([prev[None], v[:-1]])
+            marks = jnp.zeros((slots_all,), jnp.int32).at[at].add(
+                step, mode="drop")
+        with jax.named_scope("ddm.exchange"):
+            marks = lax.psum_scatter(marks, axis_name, scatter_dimension=0,
+                                     tiled=True)
+        with jax.named_scope("ddm.search"):
+            out.append(prefix_lib.shard_inclusive_cumsum(
+                marks, axis_name).astype(jnp.int32))
+    return out
+
+
+def _emit_exchange_bytes(n: int, m: int, shards: int, max_pairs: int,
+                        form: str) -> int:
+    """What :func:`_emit_sharded`'s collectives move: the scans' carries,
+    the six rank-table psums, the offsets and the count, then the slot
+    marks' reduce-scatter (``expand``) or the rows' all_to_all
+    (``search``)."""
+    from repro.core.sweep import _collective_bytes
+
+    if shards == 1:
+        return 0
+    c = 8 if _count_dtype() == jnp.int64 else 4
+    slots = shards * -(-max(max_pairs, 1) // shards)
+    total = (2 * _collective_bytes("all_gather", 4, shards)
+             + 3 * _collective_bytes("all_reduce", 4 * n, shards)
+             + 3 * _collective_bytes("all_reduce", 4 * m, shards)
+             + _collective_bytes("all_gather", c, shards)
+             + (1 if c == 8 else 2) * _collective_bytes("all_reduce", c,
+                                                      shards))
+    if form == "expand":
+        return total + 2 * (_collective_bytes("all_gather", 4, shards)
+                            + _collective_bytes("reduce_scatter", 4 * slots,
+                                               shards)
+                            + _collective_bytes("all_gather", 4, shards))
+    return total + _collective_bytes("all_to_all", 8 * slots, shards)
 
 
 # ---------------------------------------------------------------------------
@@ -406,8 +541,9 @@ def enumerate_matches(subs: Extents, upds: Extents, *, max_pairs: int,
     """
     n = subs.lo.shape[0]
     pad = (-n) % block
-    s_lo = jnp.pad(subs.lo, (0, pad), constant_values=jnp.inf).reshape(-1, block)
-    s_hi = jnp.pad(subs.hi, (0, pad), constant_values=-jnp.inf).reshape(-1, block)
+    top, bottom = runtime_lib.inert_bounds(subs.lo.dtype)
+    s_lo = jnp.pad(subs.lo, (0, pad), constant_values=top).reshape(-1, block)
+    s_hi = jnp.pad(subs.hi, (0, pad), constant_values=bottom).reshape(-1, block)
     n_blocks = s_lo.shape[0]
     base_i = jnp.arange(n_blocks, dtype=jnp.int32) * block
 

@@ -86,20 +86,34 @@ def round_up_pow2(k: int) -> int:
     return max(8, 1 << (k - 1).bit_length())
 
 
+def inert_bounds(dtype):
+    """``(top, bottom)`` of a bounds dtype: ``(+inf, -inf)`` for floats,
+    the integer range's ends for integers.  An extent ``[top, bottom]``
+    overlaps nothing but an extent that spans the whole range."""
+    import jax.numpy as jnp
+
+    if jnp.issubdtype(dtype, jnp.inexact):
+        return jnp.inf, -jnp.inf
+    info = jnp.iinfo(dtype)
+    return info.max, info.min
+
+
 def pad_axis(lo, hi, multiple: int):
     """Pad ``(d, n)`` extent columns to a multiple with inert
-    ``[+inf, -inf]`` sentinels (every closed-interval test against a
-    sentinel is False) — THE one encoding of the inert-extent convention,
-    shared by the sharded and Pallas bit-matrix paths."""
+    ``[top, bottom]`` sentinels (:func:`inert_bounds`: every closed-interval
+    test against a sentinel is False) — THE one encoding of the
+    inert-extent convention, shared by the sharded and Pallas bit-matrix
+    paths."""
     import jax.numpy as jnp
 
     pad = (-lo.shape[1]) % multiple
     if pad == 0:
         return lo, hi
     d = lo.shape[0]
+    top, bottom = inert_bounds(lo.dtype)
     return (
-        jnp.concatenate([lo, jnp.full((d, pad), jnp.inf, lo.dtype)], axis=1),
-        jnp.concatenate([hi, jnp.full((d, pad), -jnp.inf, hi.dtype)], axis=1),
+        jnp.concatenate([lo, jnp.full((d, pad), top, lo.dtype)], axis=1),
+        jnp.concatenate([hi, jnp.full((d, pad), bottom, hi.dtype)], axis=1),
     )
 
 
@@ -244,8 +258,11 @@ class MatchStats:
     block mutations (0 for non-blocked engines; DESIGN.md §13).
     ``readbacks`` counts the blocking device→host reads the call made
     before it returned (a planned 1-d sweep: the probe's four count
-    partials, then the emission's count).  ``call`` is a per-process
-    number that the call's profiler spans carry with its ``engine``.
+    partials, then the emission's count).  ``chips`` is the number of
+    chips the call's programs spanned, and ``exchange_bytes`` the bytes
+    their collectives moved between chips, computed from their shapes
+    (0 on one chip).  ``call`` is a per-process number that the call's
+    profiler spans carry with its ``engine``.
     """
 
     engine: str = ""
@@ -256,6 +273,8 @@ class MatchStats:
     recompiles: int = 0
     blocks_touched: int = 0
     readbacks: int = 0
+    chips: int = 1
+    exchange_bytes: int = 0
     attempts: List[int] = dataclasses.field(default_factory=list)
     phase_seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
     call: int = dataclasses.field(default_factory=lambda: next(_CALLS),
@@ -319,6 +338,8 @@ class MatchStats:
             "recompiles": self.recompiles,
             "blocks_touched": self.blocks_touched,
             "readbacks": self.readbacks,
+            "chips": self.chips,
+            "exchange_bytes": self.exchange_bytes,
             "attempts": list(self.attempts),
             "waste": self.waste,
             "peak_buffer_elements": self.peak_buffer_elements,

@@ -27,6 +27,7 @@ inputs (ties, duplicates, zero-length intervals included) — see
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -37,6 +38,7 @@ from jax import lax
 from repro.core import prefix as prefix_lib
 from repro.core.intervals import Extents
 from repro.core.errors import ValidationError
+from repro.core.runtime import inert_bounds
 
 
 class EndpointStream(NamedTuple):
@@ -95,17 +97,19 @@ def _emission_counts(sub_lo, sub_up, upd_lo, upd_up, cumsum_fn):
 
 
 def _pad_stream(ep: EndpointStream, multiple: int) -> EndpointStream:
-    """Pad to a segment multiple with inert sentinel endpoints (+inf lowers)."""
+    """Pad to a segment multiple with inert sentinel endpoints (lowers at
+    the top of the dtype)."""
     total = ep.values.shape[0]
     pad = (-total) % multiple
     if pad == 0:
         return ep
-    # A padded record is an update-*lower* endpoint at +inf: it increments
-    # active_upd after every real endpoint but is never emitted against
-    # (emission only happens at upper endpoints, all of which precede it).
-    inf = jnp.full((pad,), jnp.inf, ep.values.dtype)
+    # A padded record is an update-*lower* endpoint at the top of the
+    # dtype, after the sort: it increments active_upd after every real
+    # endpoint but is never emitted against (emission only happens at upper
+    # endpoints, all of which precede it).
+    top, _ = inert_bounds(ep.values.dtype)
     return EndpointStream(
-        jnp.concatenate([ep.values, inf]),
+        jnp.concatenate([ep.values, jnp.full((pad,), top, ep.values.dtype)]),
         jnp.concatenate([ep.is_upper, jnp.zeros((pad,), jnp.bool_)]),
         jnp.concatenate([ep.is_sub, jnp.zeros((pad,), jnp.bool_)]),
         jnp.concatenate([ep.owner, jnp.full((pad,), -1, jnp.int32)]),
@@ -268,7 +272,7 @@ def sbm_active_profile(subs: Extents, upds: Extents, *, num_segments: int = 8):
 # --------------------------------------------------------------------------
 
 def rank_tables_from_cumsums(is_sub, is_upper, owner, c_sub_lo, c_upd_lo,
-                             n: int, m: int, combine=lambda t: t):
+                             n: int, m: int):
     """Per-extent emission ranges from the two lower-indicator cumsums.
 
     Position-space form of the emission phase (DESIGN.md §3).  In the sorted
@@ -289,17 +293,17 @@ def rank_tables_from_cumsums(is_sub, is_upper, owner, c_sub_lo, c_upd_lo,
 
     ``is_sub``/``is_upper``: bool, ``owner``: int32 (>= 0 real, < 0 pad),
     ``c_*_lo``: int32 *global* inclusive cumsums — all aligned with the
-    (possibly sharded) stream slice this caller holds.  ``combine`` folds
-    each locally-scattered table into the global one: identity when the
-    caller holds the whole stream, a psum over the mesh axis inside
-    shard_map where each shard holds a contiguous slice.
+    (possibly sharded) stream slice this caller holds.  Every output is
+    linear in the entries scattered from the slice, so a shard holding a
+    contiguous slice psums its tables with the other shards' into the
+    whole ones.
     """
     real = owner >= 0   # padding records never contribute a table entry
 
     def scatter(count, sel, vals):
         idx = jnp.where(sel, owner, count)
-        return combine(jnp.zeros((count,), jnp.int32).at[idx].set(
-            jnp.where(sel, vals, 0), mode="drop"))
+        return jnp.zeros((count,), jnp.int32).at[idx].set(
+            jnp.where(sel, vals, 0), mode="drop")
 
     sel_s_lo = is_sub & ~is_upper & real
     sel_s_up = is_sub & is_upper & real
@@ -312,12 +316,12 @@ def rank_tables_from_cumsums(is_sub, is_upper, owner, c_sub_lo, c_upd_lo,
     b_end = scatter(m, sel_u_up, c_sub_lo)
 
     # rank → extent id (c_*_lo - 1 is this lower endpoint's 0-based rank)
-    subs_by_lo = combine(jnp.zeros((n,), jnp.int32).at[
+    subs_by_lo = jnp.zeros((n,), jnp.int32).at[
         jnp.where(sel_s_lo, c_sub_lo - 1, n)].set(
-        jnp.where(sel_s_lo, owner, 0), mode="drop"))
-    upds_by_lo = combine(jnp.zeros((m,), jnp.int32).at[
+        jnp.where(sel_s_lo, owner, 0), mode="drop")
+    upds_by_lo = jnp.zeros((m,), jnp.int32).at[
         jnp.where(sel_u_lo, c_upd_lo - 1, m)].set(
-        jnp.where(sel_u_lo, owner, 0), mode="drop"))
+        jnp.where(sel_u_lo, owner, 0), mode="drop")
     return a_start, a_end - a_start, b_start, b_end - b_start, \
         subs_by_lo, upds_by_lo
 
@@ -386,6 +390,169 @@ def active_sets_at_segment_starts(subs: Extents, upds: Extents,
 # --------------------------------------------------------------------------
 # Distributed sweep: the paper's algorithm across a device mesh axis
 # --------------------------------------------------------------------------
+#
+# Under a mesh no chip holds the whole endpoint stream (DESIGN.md §14).
+# Each chip encodes the endpoints of its slice of the bounds as two int32
+# words, a key and a tag, and sorts them; the chips agree on exact
+# splitters; one all_to_all hands each chip its contiguous share of the
+# global order, which it sorts again.  The tag orders ties as
+# encode_endpoints does, so the sharded stream is the one-chip stream.
+
+_UPPER = 1 << 30            # tag bit: an upper endpoint
+_UPD = 1 << 29              # tag bit: an update endpoint
+_OWNER = _UPD - 1           # tag bits: the owning extent's index
+
+
+def _sort_key(x: jax.Array) -> jax.Array:
+    """Order-preserving int32 image of the bounds; a float -0.0 sorts as
+    +0.0, as in :func:`encode_endpoints`."""
+    dt = x.dtype
+    if jnp.issubdtype(dt, jnp.floating) and dt.itemsize <= 4:
+        x = x.astype(jnp.float32)
+        bits = lax.bitcast_convert_type(
+            jnp.where(x == 0, jnp.float32(0), x), jnp.int32)
+        return jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    if jnp.issubdtype(dt, jnp.integer) and jnp.can_cast(dt, jnp.int32):
+        return x.astype(jnp.int32)
+    raise ValidationError(
+        f"a mesh sorts float bounds of at most 32 bits or integer bounds "
+        f"that int32 holds, not {dt}")
+
+
+def _decode_tags(tags: jax.Array, n: int, m: int):
+    """``(is_sub, is_upper, owner)`` of stream tags; ``owner`` is -1 for
+    the records of padding extents (index ≥ n or m)."""
+    is_upper = (tags & _UPPER) != 0
+    is_sub = (tags & _UPD) == 0
+    owner = tags & _OWNER
+    return is_sub, is_upper, jnp.where(owner < jnp.where(is_sub, n, m),
+                                       owner, -1)
+
+
+def _tag_indicators(tags, n: int, m: int):
+    """The four indicator streams of sorted tags; padding records are
+    inert wherever they lie."""
+    is_sub, is_upper, owner = _decode_tags(tags, n, m)
+    real = owner >= 0
+    return tuple((sel & real).astype(jnp.int32) for sel in (
+        is_sub & ~is_upper, is_sub & is_upper,
+        ~is_sub & ~is_upper, ~is_sub & is_upper))
+
+
+def _shard_endpoints(s_lo, s_hi, u_lo, u_hi, axis_name: str):
+    """This chip's endpoint records, unsorted: ``(keys, tags)``."""
+    i = lax.axis_index(axis_name)
+    ns, nu = s_lo.shape[0], u_lo.shape[0]
+    sid = i * ns + jnp.arange(ns, dtype=jnp.int32)
+    uid = _UPD | (i * nu + jnp.arange(nu, dtype=jnp.int32))
+    keys = jnp.concatenate([_sort_key(v) for v in (s_lo, s_hi, u_lo, u_hi)])
+    return keys, jnp.concatenate([sid, sid | _UPPER, uid, uid | _UPPER])
+
+
+def _count_below(keys, tags, v, t):
+    """Records of a sorted local run whose (key, tag) is below (v, t),
+    for each query: a binary search of the run."""
+    size = keys.shape[0]
+
+    def step(_, bounds):
+        lo, hi = bounds
+        mid = (lo + hi) >> 1
+        at = jnp.minimum(mid, size - 1)
+        less = (keys[at] < v) | ((keys[at] == v) & (tags[at] < t))
+        live = lo < hi
+        return (jnp.where(live & less, mid + 1, lo),
+                jnp.where(live & ~less, mid, hi))
+
+    lo = jnp.zeros(v.shape, jnp.int32)
+    return lax.fori_loop(0, math.ceil(math.log2(size + 1)), step,
+                         (lo, jnp.full(v.shape, size, jnp.int32)))[0]
+
+
+def _exact_cuts(keys, tags, axis_name: str, num_shards: int):
+    """Where this chip's sorted run splits among the shards.
+
+    Piece q, ``[cuts[q], cuts[q+1])``, goes to shard q, and shard q gets
+    exactly the global ranks ``[q·S, (q+1)·S)`` (S the run length).  Keys
+    are distinct, as a tag names its record, so the key of global rank r
+    is the largest with at most r keys below it across the mesh: it is
+    built bit by bit, key word first, then tag word.
+    """
+    size = keys.shape[0]
+    ranks = jnp.arange(1, num_shards, dtype=jnp.int32) * size
+    zero = jnp.zeros_like(ranks)
+
+    def below(v, t):
+        return lax.psum(_count_below(keys, tags, v, t), axis_name)
+
+    def key_bit(b, u):          # u: the key plus 2^31, as uint32
+        cand = u | (jnp.uint32(1 << 31) >> b.astype(jnp.uint32))
+        v = lax.bitcast_convert_type(cand ^ jnp.uint32(1 << 31), jnp.int32)
+        return jnp.where(below(v, zero) <= ranks, cand, u)
+
+    u = lax.fori_loop(0, 32, key_bit, jnp.zeros(ranks.shape, jnp.uint32))
+    v = lax.bitcast_convert_type(u ^ jnp.uint32(1 << 31), jnp.int32)
+
+    def tag_bit(b, t):
+        cand = t | (jnp.int32(1 << 30) >> b)
+        return jnp.where(below(v, cand) <= ranks, cand, t)
+
+    t = lax.fori_loop(0, 31, tag_bit, zero)
+    return jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                            _count_below(keys, tags, v, t),
+                            jnp.full((1,), size, jnp.int32)])
+
+
+def _exchange(cuts, arrays, axis_name: str, num_shards: int):
+    """Send piece q of each sorted local array to shard q with one
+    all_to_all of S-record windows (S the run length, the largest a
+    piece can be); each shard lays the pieces it gets end to end."""
+    size = arrays[0].shape[0]
+    got = lax.all_to_all(cuts[1:] - cuts[:-1], axis_name, 0, 0)
+    at = jnp.cumsum(got) - got
+
+    def send(x):
+        twice = jnp.concatenate([x, x])
+        return jnp.stack([lax.dynamic_slice(twice, (cuts[q],), (size,))
+                          for q in range(num_shards)])
+
+    out = []
+    for x in arrays:
+        recv = lax.all_to_all(send(x), axis_name, 0, 0)
+        # piece p fills [at[p], at[p] + got[p]); the rest of its window
+        # is overwritten by piece p + 1, the last one's falls past S
+        buf = jnp.zeros((2 * size,), x.dtype)
+        for p in range(num_shards):
+            buf = lax.dynamic_update_slice(buf, recv[p], (at[p],))
+        out.append(buf[:size])
+    return out
+
+
+def _sort_shard(s_lo, s_hi, u_lo, u_hi, *, axis_name: str,
+                num_shards: int) -> jax.Array:
+    """Shard body: the sorted tags of this shard's contiguous range of the
+    global endpoint stream, S = 2·(local n + local m) records on every
+    shard, from this chip's slices of the bounds."""
+    with jax.named_scope("ddm.sort"):
+        keys, tags = lax.sort(
+            _shard_endpoints(s_lo, s_hi, u_lo, u_hi, axis_name), num_keys=2)
+    if num_shards == 1:
+        return tags
+    with jax.named_scope("ddm.exchange"):
+        cuts = _exact_cuts(keys, tags, axis_name, num_shards)
+        keys, tags = _exchange(cuts, (keys, tags), axis_name, num_shards)
+    with jax.named_scope("ddm.sort"):
+        return lax.sort((keys, tags), num_keys=2)[1]
+
+
+def _shard_lane_partials(sub_lo, sub_up, upd_lo, upd_up, axis_name: str):
+    """The counting sweep over contiguous shards of the stream: the K's
+    four lane partials (:func:`_lane_partial_sums`), psum'd."""
+    def cumsum_fn(x):
+        return prefix_lib.shard_inclusive_cumsum(x, axis_name)
+
+    emit = _emission_counts(sub_lo, sub_up, upd_lo, upd_up, cumsum_fn)
+    return tuple(lax.psum(v, axis_name) for v in _lane_partial_sums(emit))
+
 
 def sbm_count_shard_body(sub_lo, sub_up, upd_lo, upd_up, *, axis_name: str):
     """Per-shard body (call inside shard_map over contiguous sorted shards).
@@ -398,34 +565,88 @@ def sbm_count_shard_body(sub_lo, sub_up, upd_lo, upd_up, *, axis_name: str):
     :func:`_lane_partial_sums`) and the result is exact int64 under x64,
     saturating at 2³¹−1 without — never a silent wrap.
     """
-    def cumsum_fn(x):
-        return prefix_lib.shard_inclusive_cumsum(x, axis_name)
-
-    emit = _emission_counts(sub_lo, sub_up, upd_lo, upd_up, cumsum_fn)
-    a, b, c, d = (lax.psum(v, axis_name) for v in _lane_partial_sums(emit))
-    return combine_lane_partials(a, b, c, d)
+    return combine_lane_partials(*_shard_lane_partials(
+        sub_lo, sub_up, upd_lo, upd_up, axis_name))
 
 
-def sbm_count_sharded(subs: Extents, upds: Extents, mesh, axis_name: str):
-    """End-to-end distributed SBM count over one mesh axis.
+def _pad_to(x, multiple: int):
+    """``x`` padded to a multiple with the top of its dtype."""
+    pad = (-x.shape[0]) % multiple
+    if pad == 0:
+        return x
+    top, _ = inert_bounds(x.dtype)
+    return jnp.concatenate([x, jnp.full((pad,), top, x.dtype)])
 
-    Sort runs under jit (XLA parallel sort); the sweep is shard_mapped: each
-    device scans a contiguous segment of the sorted stream and the active-set
-    carry crosses devices via the two-level scan (all_gather of partials).
+
+def _sort_count_body(s_lo, s_hi, u_lo, u_hi, *, n, m, axis_name,
+                     num_shards):
+    tags = _sort_shard(s_lo, s_hi, u_lo, u_hi, axis_name=axis_name,
+                       num_shards=num_shards)
+    with jax.named_scope("ddm.count"):
+        return tags, _shard_lane_partials(*_tag_indicators(tags, n, m),
+                                          axis_name)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "axis_name"))
+def _sort_count_sharded(subs: Extents, upds: Extents, *, mesh,
+                        axis_name: str):
+    """The sorted stream's tags, sharded over ``axis_name``, and K's four
+    lane partials: the probe of a planned sweep on a mesh.
+
+    The bounds may come in sharded over the axis; sets whose size is not
+    a multiple of the shard count are padded with extents whose records
+    are inert.  Both sets must hold fewer than 2²⁹ extents.
     """
     from jax.sharding import PartitionSpec as P
 
+    n, m = subs.lo.shape[0], upds.lo.shape[0]
+    if max(n, m) >= _UPD:
+        raise ValidationError(f"a mesh sweep takes fewer than 2^29 extents "
+                              f"a side, not n={n}, m={m}")
     num_shards = mesh.shape[axis_name]
-    ep = _pad_stream(encode_endpoints(subs, upds), num_shards)
-    sub_lo, sub_up, upd_lo, upd_up = _indicator_deltas(ep)
+    fn = jax.shard_map(
+        functools.partial(_sort_count_body, n=n, m=m, axis_name=axis_name,
+                          num_shards=num_shards),
+        mesh=mesh, in_specs=(P(axis_name),) * 4,
+        out_specs=(P(axis_name), P()), check_vma=False)
+    return fn(*(_pad_to(x, num_shards)
+                for x in (subs.lo, subs.hi, upds.lo, upds.hi)))
 
-    fn = jax.jit(jax.shard_map(
-        functools.partial(sbm_count_shard_body, axis_name=axis_name),
-        mesh=mesh,
-        in_specs=(P(axis_name), P(axis_name), P(axis_name), P(axis_name)),
-        out_specs=P(),
-    ))
-    return fn(sub_lo, sub_up, upd_lo, upd_up)
+
+def sbm_count_sharded(subs: Extents, upds: Extents, mesh, axis_name: str):
+    """End-to-end distributed SBM count over one mesh axis: the sort across
+    the mesh, then the counting sweep over contiguous shards of the stream,
+    the active-set carry crossing chips by the two-level scan.  The same
+    overflow contract as :func:`sbm_count`."""
+    _, partials = _sort_count_sharded(subs, upds, mesh=mesh,
+                                      axis_name=axis_name)
+    return combine_lane_partials(*partials)
+
+
+def _collective_bytes(kind: str, nbytes: int, shards: int) -> int:
+    """Bytes a collective moves between chips, summed over the chips, for
+    ``nbytes`` of operand on each: a ring all-reduce 2(P−1)·X, an
+    all-gather P(P−1)·X, an all-to-all or a reduce-scatter (P−1)·X."""
+    factor = {"all_reduce": 2 * (shards - 1),
+              "all_gather": shards * (shards - 1),
+              "all_to_all": shards - 1,
+              "reduce_scatter": shards - 1}[kind]
+    return factor * nbytes
+
+
+def _sort_count_exchange_bytes(n: int, m: int, shards: int) -> int:
+    """What :func:`_sort_count_sharded`'s collectives move: the splitter
+    search (63 psums of P−1 counts), the all_to_all of the piece sizes
+    and of two int32 words per record in S-record windows, the scans'
+    carries and the lane psums."""
+    if shards == 1:
+        return 0
+    size = 2 * (-(-n // shards) + -(-m // shards))
+    return (63 * _collective_bytes("all_reduce", 4 * (shards - 1), shards)
+            + _collective_bytes("all_to_all", 4 * shards, shards)
+            + 2 * _collective_bytes("all_to_all", 4 * shards * size, shards)
+            + 4 * _collective_bytes("all_gather", 4, shards)
+            + 4 * _collective_bytes("all_reduce", 4, shards))
 
 
 # --------------------------------------------------------------------------

@@ -59,12 +59,13 @@ _SCRIPT = textwrap.dedent("""
     assert pairs.shape == (-(-(len(want_pairs) + 32) // 8) * 8, 2)
     assert len(pairs.sharding.device_set) == 8
     assert not pairs.sharding.is_fully_replicated
-    # a per-shard cap below every shard's share drops pairs, never the count
+    # a buffer below K drops pairs, never the count: it holds the first
+    # max_pairs of them
     capped, cnt_c = sbm_enumerate_sharded(subs, upds, mesh, "p",
-                                          max_pairs=len(want_pairs),
-                                          max_pairs_per_shard=4)
+                                          max_pairs=len(want_pairs) // 2)
     got_c = {(int(i), int(j)) for i, j in np.asarray(capped) if i >= 0}
     assert int(cnt_c) == len(want_pairs) and got_c < want_pairs
+    assert len(got_c) == len(want_pairs) // 2
 
     # d-dim bit-matrix sharded over subscription rows (n not a shard
     # multiple -> inert-row padding): words and count must equal the

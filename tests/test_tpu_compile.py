@@ -8,6 +8,7 @@ N = 10⁶ extents (2·10⁶ endpoints, n = 5·10⁵ bitmask ids per side) for th
 sweep kernels, d = 2 and n = m = 8192 for the bit-matrix kernel.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +125,54 @@ def test_bitmatrix_kernel_compiles(one_chip):
     _compiles_to_kernel(bitmatch._bitmatrix_pallas_jit.lower(
         subs, subs, upds, upds, block_n=256, word_block=m // 32,
         interpret=False))
+
+
+COLLECTIVE = re.compile(r"\b(all-reduce|all-gather|all-to-all|reduce-scatter|"
+                        r"collective-permute)(-start|-done)?\(")
+
+
+@pytest.mark.parametrize("form,max_pairs", [("expand", 1 << 16),
+                                            ("search", 64)])
+def test_mesh_collectives_compile_as_synchronous_ops(topo, form, max_pairs):
+    """Every collective of the planned sweep's two mesh programs compiles
+    for a v5e:2x2 into one synchronous all-reduce or all-to-all
+    operation: no reduce-scatter (the slot marks' psum_scatter is an
+    all-reduce), no asynchronous start/done halves, none inside a fusion.
+    A trace's operations of those kinds then cover the collectives'
+    device time, which is what ``exchange_ms`` reads."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import Extents
+    from repro.core.enumerate import _emit_sharded, _slot_map
+    from repro.core.sweep import _sort_count_sharded
+
+    n = m = 4096
+    mesh = Mesh(topo.devices, ("p",))
+    where = NamedSharding(mesh, P("p"))
+
+    def ext(size):
+        return Extents(*(jax.ShapeDtypeStruct((size,), jnp.int32,
+                                              sharding=where)
+                         for _ in range(2)))
+
+    probe = jax.jit(lambda s, u: _sort_count_sharded(s, u, mesh=mesh,
+                                                     axis_name="p"))
+    tags = jax.eval_shape(probe, ext(n), ext(m))[0]
+    tags = jax.ShapeDtypeStruct(tags.shape, tags.dtype, sharding=where)
+    assert _slot_map(max_pairs, tags.shape[0] // 4) == form
+    for lowered in (probe.lower(ext(n), ext(m)),
+                    _emit_sharded.lower(tags, n=n, m=m, max_pairs=max_pairs,
+                                        mesh=mesh, axis_name="p")):
+        text = lowered.compile().as_text()
+        kinds, fused = set(), set()
+        computation = ""
+        for line in text.splitlines():
+            if not line.startswith(" ") and "{" in line:
+                computation = line
+            found = COLLECTIVE.search(line.partition(", metadata")[0])
+            if found:
+                kinds.add(found.group(1) + (found.group(2) or ""))
+                if "fused" in computation:
+                    fused.add(found.group(0))
+        assert kinds and kinds <= {"all-reduce", "all-to-all"}, kinds
+        assert not fused, fused

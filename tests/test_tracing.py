@@ -18,32 +18,56 @@ import jax
 import pytest
 
 from repro.core import make_uniform_workload, runtime
-from repro.core.enumerate import (_sbm_enumerate_jit, _slot_map,
-                                  sbm_enumerate_planned)
-from repro.core.sweep import _sbm_count_partials
+from repro.core.enumerate import (_emit_sharded, _sbm_enumerate_jit,
+                                  _slot_map, sbm_enumerate_planned)
+from repro.core.sweep import _sbm_count_partials, _sort_count_sharded
 
 jax.config.update("jax_platform_name", "cpu")
 
 PROBE_SCOPES = {"ddm.sort", "ddm.count"}
 EMIT_SCOPES = {"ddm.sort", "ddm.ranks", "ddm.search", "ddm.gather"}
+MESH_EMIT_SCOPES = {"ddm.ranks", "ddm.exchange", "ddm.search", "ddm.gather"}
+MESH = jax.make_mesh((1,), ("p",))
 
 
 def _workload(seed=0):
     return make_uniform_workload(jax.random.PRNGKey(seed), 300, 400, 2.0)
 
 
-# (name, jitted function, static arguments) of the planned sweep's programs;
-# with n+m = 700 the emission expands 1024 slots and searches for 64
+def _sets(subs, upds):
+    return subs, upds
+
+
+def _stream(subs, upds):
+    return _sort_count_sharded(subs, upds, mesh=MESH, axis_name="p")[:1]
+
+
+# (name, jitted function, its arguments from the sets, static arguments,
+# stages) of the planned sweep's programs on one chip and on a mesh (one
+# device here; tests/test_core_mesh.py compiles them for four); with
+# n+m = 700 the emission expands 1024 slots and searches for 64
 PROGRAMS = [
-    ("count", _sbm_count_partials,
-     dict(num_segments=8, scan_impl="two_level")),
-    ("emit", _sbm_enumerate_jit,
-     dict(max_pairs=1024, num_segments=8, scan_impl="two_level")),
-    ("emit_search", _sbm_enumerate_jit,
-     dict(max_pairs=64, num_segments=8, scan_impl="two_level")),
+    ("count", _sbm_count_partials, _sets,
+     dict(num_segments=8, scan_impl="two_level"), PROBE_SCOPES),
+    ("emit", _sbm_enumerate_jit, _sets,
+     dict(max_pairs=1024, num_segments=8, scan_impl="two_level"),
+     EMIT_SCOPES),
+    ("emit_search", _sbm_enumerate_jit, _sets,
+     dict(max_pairs=64, num_segments=8, scan_impl="two_level"), EMIT_SCOPES),
+    ("mesh_count", _sort_count_sharded, _sets,
+     dict(mesh=MESH, axis_name="p"), PROBE_SCOPES),
+    ("mesh_emit", _emit_sharded, _stream,
+     dict(n=300, m=400, max_pairs=1024, mesh=MESH, axis_name="p"),
+     MESH_EMIT_SCOPES),
+    ("mesh_emit_search", _emit_sharded, _stream,
+     dict(n=300, m=400, max_pairs=64, mesh=MESH, axis_name="p"),
+     MESH_EMIT_SCOPES),
 ]
-# the loops each program keeps: only the binary search is a while
-LOOPS = {"count": {"sort"}, "emit": {"sort"}, "emit_search": {"sort", "while"}}
+# the loops each program keeps: only the binary search is a while (the
+# splitter search of the sort across the mesh needs two or more chips)
+LOOPS = {"count": {"sort"}, "emit": {"sort"}, "emit_search": {"sort", "while"},
+         "mesh_count": {"sort"}, "mesh_emit": set(),
+         "mesh_emit_search": {"while"}}
 
 
 def _instructions(hlo: str):
@@ -206,12 +230,11 @@ def test_every_stage_is_named_in_the_compiled_programs():
     name relative to it, like ``or``.)"""
     subs, upds = _workload(seed=2)
     seen = set()
-    for name, fn, static in PROGRAMS:
-        hlo = fn.lower(subs, upds, **static).compile().as_text()
+    for name, fn, args, static, stages in PROGRAMS:
+        hlo = fn.lower(*args(subs, upds), **static).compile().as_text()
         insts = _instructions(hlo)
         scopes = {_scope(op) for _, op in insts} - {None}
-        assert scopes == (PROBE_SCOPES if name == "count" else EMIT_SCOPES), \
-            name
+        assert scopes == stages, name
         seen |= scopes
         rooted = [op for _, op in insts
                   if op and op.startswith("jit(") and not _scope(op)]
@@ -219,7 +242,7 @@ def test_every_stage_is_named_in_the_compiled_programs():
         loops = [(opc, op) for opc, op in insts if opc in ("sort", "while")]
         assert {opc for opc, _ in loops} == LOOPS[name], name
         assert all(_scope(op) for _, op in loops), (name, loops)
-    assert seen == PROBE_SCOPES | EMIT_SCOPES
+    assert seen == PROBE_SCOPES | EMIT_SCOPES | MESH_EMIT_SCOPES
 
 
 def test_named_scopes_are_metadata_only(monkeypatch):
@@ -227,17 +250,51 @@ def test_named_scopes_are_metadata_only(monkeypatch):
     stage scopes and without them."""
     subs, upds = _workload(seed=3)
     with_scopes = {}
-    for name, fn, static in PROGRAMS:
+    for name, fn, args, static, _ in PROGRAMS:
         fresh = jax.jit(fn.__wrapped__, static_argnames=tuple(static))
-        with_scopes[name] = fresh.lower(subs, upds, **static).compile(
-        ).as_text()
+        with_scopes[name] = fresh.lower(*args(subs, upds), **static
+                                        ).compile().as_text()
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     jax.clear_caches()                     # trace the programs again
-    for name, fn, static in PROGRAMS:
+    for name, fn, args, static, _ in PROGRAMS:
         fresh = jax.jit(fn.__wrapped__, static_argnames=tuple(static))
-        bare = fresh.lower(subs, upds, **static).compile().as_text()
+        bare = fresh.lower(*args(subs, upds), **static).compile().as_text()
         assert "ddm." not in bare
         assert "ddm." in with_scopes[name]
         assert _strip_metadata(bare) == _strip_metadata(with_scopes[name]), \
             name
+
+
+# Optimized HLO, metadata stripped, of the one-chip programs at the static
+# cells' shapes (float32 bounds, n = m = 5·10⁵; 8,192 rows and 2²⁶), on
+# the CPU backend: the fingerprints of these programs before integer
+# bounds and the mesh path, which leave the float32 programs as they were.
+FLOAT32_HLO = {
+    "count": (_sbm_count_partials, dict(num_segments=8,
+                                        scan_impl="two_level"),
+              "67374da03d38841f"),
+    "emit": (_sbm_enumerate_jit, dict(max_pairs=8192, num_segments=8,
+                                      scan_impl="two_level"),
+             "02d6854ac9d107c3"),
+    "emit_2e26": (_sbm_enumerate_jit, dict(max_pairs=1 << 26, num_segments=8,
+                                           scan_impl="two_level"),
+                  "da45371356a35d33"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FLOAT32_HLO))
+def test_float32_programs_keep_their_hlo(name):
+    import hashlib
+
+    import jax.numpy as jnp
+
+    from repro.core import Extents
+
+    fn, static, want = FLOAT32_HLO[name]
+    bounds = jax.ShapeDtypeStruct((500_000,), jnp.float32)
+    with jax.enable_x64(False):
+        hlo = fn.lower(Extents(bounds, bounds), Extents(bounds, bounds),
+                       **static).compile().as_text()
+    got = hashlib.sha256(_strip_metadata(hlo).encode()).hexdigest()[:16]
+    assert got == want
