@@ -43,7 +43,7 @@ from repro.core.errors import ValidationError
 from repro.core.sweep import (_lane_partial_sums, _pad_stream,
                               _saturate_from_lanes, _decode_tags,
                               emission_rank_tables, encode_endpoints,
-                              rank_tables_from_cumsums, resolve_cumsum)
+                              resolve_cumsum)
 
 
 def _count_dtype():
@@ -348,12 +348,13 @@ def _emit_shard_body(tags, *, n, m, max_pairs, per_shard, shards, axis_name,
     """Shard body of the emission; ``tags`` is this shard's contiguous
     range of the sorted stream.
 
-    The rank tables are the single-chip construction over the shard's
-    records, psum'd into whole (n,)/(m,) tables on every shard.  Every
-    stream position is an emitter: an upper endpoint emits its extent's
-    class count, any other record nothing, and emitters in stream order
-    own consecutive global slots from their shard's exclusive offset.
-    Shard q owns output slots ``[q·per_shard, (q+1)·per_shard)``.
+    Every stream position is an emitter: an upper endpoint emits its
+    extent's class count, any other record nothing, and emitters in stream
+    order own consecutive global slots from their shard's exclusive
+    offset.  An emitter reads its count and its counterparts' first rank
+    from its own cumsums and its opening rank, one gather from the psum'd
+    join table (:func:`_join_tables`).  Shard q owns output slots
+    ``[q·per_shard, (q+1)·per_shard)``.
     """
     cdtype = _count_dtype()
     slots_all = shards * per_shard
@@ -367,23 +368,23 @@ def _emit_shard_body(tags, *, n, m, max_pairs, per_shard, shards, axis_name,
         c_upd_lo = prefix_lib.shard_inclusive_cumsum(
             (~is_sub & ~is_upper & real).astype(jnp.int32),
             axis_name).astype(jnp.int32)
-        local = rank_tables_from_cumsums(is_sub, is_upper, owner, c_sub_lo,
-                                         c_upd_lo, n, m)
+        local = _join_tables(is_sub, is_upper, owner, c_sub_lo, c_upd_lo,
+                             n, m)
     with jax.named_scope("ddm.exchange"):
         # each table is linear in its scattered entries: psum the shards'
-        a_start, a_cnt, b_start, b_cnt, subs_by_lo, upds_by_lo = (
-            lax.psum(t, axis_name) for t in local)
+        open_at, table = (lax.psum(t, axis_name) for t in local)
     with jax.named_scope("ddm.ranks"):
         sel_s = is_sub & is_upper & real
         sel_u = ~is_sub & is_upper & real
-        o_s = jnp.where(sel_s, owner, 0)
-        o_u = jnp.where(sel_u, owner, 0)
-        cnt = jnp.where(sel_s, a_cnt[o_s], jnp.where(sel_u, b_cnt[o_u], 0))
-        # the emitter's code (sub i, or n + update j) and where its
-        # counterparts start in table = [upds_by_lo, subs_by_lo]
-        code = jnp.where(sel_s, o_s, n + o_u)
-        start = jnp.where(sel_s, a_start[o_s], m + b_start[o_u])
-        table = jnp.concatenate([upds_by_lo, subs_by_lo])
+        # the emitter's code (sub i, or n + update j); other records read
+        # entry n and emit nothing
+        code = jnp.where(sel_s, owner, n + jnp.where(sel_u, owner, 0))
+        opened = open_at[code]
+        # counterpart lowers seen here less those seen at the opening;
+        # they start at that rank in table = [upds_by_lo, subs_by_lo]
+        cnt = jnp.where(sel_s, c_upd_lo - opened,
+                        jnp.where(sel_u, c_sub_lo - opened, 0))
+        start = jnp.where(sel_s, opened, m + opened)
         lc, local_total = _shard_offsets(cnt)
     with jax.named_scope("ddm.exchange"):
         base = prefix_lib.shard_exclusive_offsets(local_total, axis_name)
@@ -448,6 +449,31 @@ def _emit_shard_body(tags, *, n, m, max_pairs, per_shard, shards, axis_name,
         return jnp.max(recv, axis=0), k_total
 
 
+def _join_tables(is_sub, is_upper, owner, c_sub_lo, c_upd_lo, n: int,
+                 m: int):
+    """This shard's entries of the mesh emission's two (n + m,) tables.
+
+    ``open_at``: at sub i, the update-lower cumsum at its lower record; at
+    n + j, the sub-lower cumsum at update j's.  ``table``: rank → id,
+    ``[upds_by_lo, subs_by_lo]``.  Only lower records write, each entry
+    once across the stream, so the shards' tables psum into the whole
+    ones.  (The one-chip emission's emitters are extent ids and read
+    :func:`repro.core.sweep.rank_tables_from_cumsums`' id-indexed tables
+    directly.)
+    """
+    lower = ~is_upper & (owner >= 0)
+    s_lo = is_sub & lower
+    u_lo = ~is_sub & lower
+    size = n + m    # past the end: dropped
+    at = jnp.where(s_lo, owner, jnp.where(u_lo, n + owner, size))
+    open_at = jnp.zeros((size,), jnp.int32).at[at].set(
+        jnp.where(is_sub, c_upd_lo, c_sub_lo), mode="drop")
+    rank = jnp.where(u_lo, c_upd_lo - 1, jnp.where(s_lo, m + c_sub_lo - 1,
+                                                    size))
+    table = jnp.zeros((size,), jnp.int32).at[rank].set(owner, mode="drop")
+    return open_at, table
+
+
 def _shard_offsets(cnt: jax.Array):
     """The inclusive offsets of a shard's emitters and its total, under
     :func:`_offset_cumsum`'s contract: int64 under x64; without it the
@@ -500,7 +526,7 @@ def _expand_slots_sharded(excl, values, slots_all: int, axis_name: str):
 def _emit_exchange_bytes(n: int, m: int, shards: int, max_pairs: int,
                         form: str) -> int:
     """What :func:`_emit_sharded`'s collectives move: the scans' carries,
-    the six rank-table psums, the offsets and the count, then the slot
+    the two join-table psums, the offsets and the count, then the slot
     marks' reduce-scatter (``expand``) or the rows' all_to_all
     (``search``)."""
     from repro.core.sweep import _collective_bytes
@@ -510,8 +536,7 @@ def _emit_exchange_bytes(n: int, m: int, shards: int, max_pairs: int,
     c = 8 if _count_dtype() == jnp.int64 else 4
     slots = shards * -(-max(max_pairs, 1) // shards)
     total = (2 * _collective_bytes("all_gather", 4, shards)
-             + 3 * _collective_bytes("all_reduce", 4 * n, shards)
-             + 3 * _collective_bytes("all_reduce", 4 * m, shards)
+             + 2 * _collective_bytes("all_reduce", 4 * (n + m), shards)
              + _collective_bytes("all_gather", c, shards)
              + (1 if c == 8 else 2) * _collective_bytes("all_reduce", c,
                                                       shards))

@@ -25,6 +25,7 @@ _SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.core import (Extents, sbm_enumerate_planned,
                             sbm_enumerate_sharded)
+    from repro.core.enumerate import _slot_map
     from repro.core.runtime import pairs_via_retry
     from repro.core.sweep import (_decode_tags, encode_endpoints,
                                   sequential_sbm_pairs_numpy,
@@ -134,6 +135,64 @@ _SCRIPT = textwrap.dedent("""
         out["saturated_" + form] = {
             "count": int(count), "blank": bool((a == -1).all()),
             "rows": int(a.shape[0])}
+    # Each emitter on the mesh reads its opening rank from the join table
+    # that its extent's lower record wrote, on whatever shard that lies.
+    # Every case runs both slot maps, at a buffer of K (search) and of
+    # 2^13 rows (expand).
+    def cat(*parts):
+        return np.concatenate([np.asarray(p, np.int64) for p in parts])
+
+    def crossing(dtype, swap):
+        # three long extents open in shard 0 and close in shard 3; between
+        # lie same-side points, which never match them, and 20 counterpart
+        # points; 400 counterpart points lie outside
+        lo_long = 1000 + np.arange(3)
+        same = 2000 + 800 * np.arange(1000)
+        other = cat(1 + 4 * np.arange(200), 5003 + 40000 * np.arange(20),
+                    900001 + 4 * np.arange(200))
+        a = ext(cat(lo_long, same), cat(899000 - np.arange(3), same), dtype)
+        b = ext(other, other, dtype)
+        return ((b, a) if swap else (a, b)), 3
+
+    def upper_only():
+        # 600 nested subscriptions open before the last shard and all close
+        # in it; the updates are points below them, but four inside
+        lo = 1000 + 2 * np.arange(600)
+        upd = cat(2 * np.arange(396), [1003, 1011, 1021, 1041])
+        return (ext(lo, lo + 1000000, "int32"), ext(upd, upd, "int32")), 0
+
+    JOIN_CASES = {
+        "cross_subs_i32": lambda: crossing("int32", False),
+        "cross_upds_f32": lambda: crossing("float32", True),
+        "upper_only_shard_i32": upper_only,
+        "n_ne_m_i32": lambda: (rand(6, 60, 1500, "int32", 400000, 300), 0),
+    }
+    for name, make in JOIN_CASES.items():
+        (subs, upds), n_long = make()
+        n, m = subs.size, upds.size
+        want = sequential_sbm_pairs_numpy(subs, upds)
+        one, _, _ = sbm_enumerate_planned(subs, upds)
+        tags, _ = _sort_count_sharded(subs, upds, mesh=mesh, axis_name="p")
+        shard = tags.shape[0] // 4
+        is_sub, is_upper, owner = (np.asarray(x) for x in
+                                   _decode_tags(tags, n, m))
+        real = owner >= 0
+        where = np.arange(tags.shape[0]) // shard
+        # the long extents are the first of the crossing side
+        side = is_sub == (name != "cross_upds_f32")
+        long_ = real & side & (owner < n_long)
+        r = {"want": len(want), "n": n, "m": m,
+             "lower_shards": sorted(set(where[long_ & ~is_upper].tolist())),
+             "upper_shards": sorted(set(where[long_ & is_upper].tolist())),
+             "upper_only_shards": [q for q in range(4) if real[where == q].any()
+                                   and is_upper[real & (where == q)].all()]}
+        for form, cap in (("search", max(len(want), 1)), ("expand", 2**13)):
+            pairs, count = sbm_enumerate_sharded(subs, upds, mesh, "p",
+                                                 max_pairs=cap)
+            r[form] = {"form": _slot_map(cap, shard), "k": int(count),
+                       "pairs_ok": pair_set(pairs) == want,
+                       "one_chip_ok": pair_set(pairs) == pair_set(one)}
+        out[name] = r
     print("MESH", json.dumps(out))
 """)
 
@@ -186,3 +245,58 @@ def test_emission_on_a_mesh_blanks_when_one_shard_saturates(results, form,
     r = results["saturated_" + form]
     assert r["count"] == 2**31 - 1 and r["rows"] == rows
     assert r["blank"]
+
+
+JOIN_CASES = ["cross_subs_i32", "cross_upds_f32", "upper_only_shard_i32",
+              "n_ne_m_i32"]
+
+
+@pytest.mark.parametrize("form", ["search", "expand"])
+@pytest.mark.parametrize("case", JOIN_CASES)
+def test_emitters_read_their_opening_rank_across_the_mesh(results, case,
+                                                          form):
+    """Each emitter's count and counterpart start come from its opening
+    rank in the join table, written on the shard that holds its lower
+    record: the pairs are the reference's and the one-chip call's."""
+    r = results[case]
+    assert r[form]["form"] == form
+    assert r[form]["k"] == r["want"] > 0
+    assert r[form]["pairs_ok"] and r[form]["one_chip_ok"]
+    if case.startswith("cross_"):
+        # the long extents open in shard 0 and close in shard 3
+        assert r["lower_shards"] == [0] and r["upper_shards"] == [3]
+    if case == "upper_only_shard_i32":
+        assert 3 in r["upper_only_shards"]
+    if case == "n_ne_m_i32":
+        assert r["n"] != r["m"]
+
+
+@pytest.mark.parametrize("x64", [False, True])
+@pytest.mark.parametrize("form,max_pairs", [("expand", 1 << 13),
+                                            ("search", 61)])
+def test_emission_exchange_bytes_count_its_collectives(form, max_pairs, x64):
+    """``MatchStats.exchange_bytes`` of the mesh emission at n ≠ m, term
+    by term: a ring all-reduce moves 2(P−1)·X, an all-gather P(P−1)·X, an
+    all-to-all or a reduce-scatter (P−1)·X, X bytes a chip."""
+    import jax
+
+    from repro.core.enumerate import _emit_exchange_bytes
+
+    n, m, p = 1003, 420, 4
+    c = 8 if x64 else 4                      # a pair count's bytes
+    slots = p * -(-max_pairs // p)
+    all_reduce, all_gather, one_way = 2 * (p - 1), p * (p - 1), p - 1
+    want = (2 * all_gather * 4               # the lower cumsums' carries
+            + 2 * all_reduce * 4 * (n + m)   # open_at and table psums
+            + all_gather * c                 # the shards' offsets
+            # the count: one int64 psum, or two psums of 15-bit lanes
+            + (1 if x64 else 2) * all_reduce * c)
+    if form == "expand":
+        # per slot value (emitter code, base): the last record's value,
+        # the marks' reduce-scatter, the slot scan's carry
+        want += 2 * (all_gather * 4 + one_way * 4 * slots + all_gather * 4)
+    else:
+        want += one_way * 8 * slots          # the rows' all_to_all
+    with jax.enable_x64(x64):
+        assert _emit_exchange_bytes(n, m, p, max_pairs, form) == want
+        assert _emit_exchange_bytes(n, m, 1, max_pairs, form) == 0

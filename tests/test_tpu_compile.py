@@ -131,22 +131,18 @@ COLLECTIVE = re.compile(r"\b(all-reduce|all-gather|all-to-all|reduce-scatter|"
                         r"collective-permute)(-start|-done)?\(")
 
 
-@pytest.mark.parametrize("form,max_pairs", [("expand", 1 << 16),
-                                            ("search", 64)])
-def test_mesh_collectives_compile_as_synchronous_ops(topo, form, max_pairs):
-    """Every collective of the planned sweep's two mesh programs compiles
-    for a v5e:2x2 into one synchronous all-reduce or all-to-all
-    operation: no reduce-scatter (the slot marks' psum_scatter is an
-    all-reduce), no asynchronous start/done halves, none inside a fusion.
-    A trace's operations of those kinds then cover the collectives'
-    device time, which is what ``exchange_ms`` reads."""
+MESH_FORMS = [("expand", 1 << 16), ("search", 64)]
+
+
+def _mesh_programs(topo, form, max_pairs, n=4096, m=4096):
+    """The planned sweep's probe and emission on a described v5e:2x2,
+    lowered, with the shard's stream length."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.core import Extents
     from repro.core.enumerate import _emit_sharded, _slot_map
     from repro.core.sweep import _sort_count_sharded
 
-    n = m = 4096
     mesh = Mesh(topo.devices, ("p",))
     where = NamedSharding(mesh, P("p"))
 
@@ -159,10 +155,23 @@ def test_mesh_collectives_compile_as_synchronous_ops(topo, form, max_pairs):
                                                      axis_name="p"))
     tags = jax.eval_shape(probe, ext(n), ext(m))[0]
     tags = jax.ShapeDtypeStruct(tags.shape, tags.dtype, sharding=where)
-    assert _slot_map(max_pairs, tags.shape[0] // 4) == form
-    for lowered in (probe.lower(ext(n), ext(m)),
-                    _emit_sharded.lower(tags, n=n, m=m, max_pairs=max_pairs,
-                                        mesh=mesh, axis_name="p")):
+    shard = tags.shape[0] // 4
+    assert _slot_map(max_pairs, shard) == form
+    return (probe.lower(ext(n), ext(m)),
+            _emit_sharded.lower(tags, n=n, m=m, max_pairs=max_pairs,
+                                mesh=mesh, axis_name="p"), shard)
+
+
+@pytest.mark.parametrize("form,max_pairs", MESH_FORMS)
+def test_mesh_collectives_compile_as_synchronous_ops(topo, form, max_pairs):
+    """Every collective of the planned sweep's two mesh programs compiles
+    for a v5e:2x2 into one synchronous all-reduce or all-to-all
+    operation: no reduce-scatter (the slot marks' psum_scatter is an
+    all-reduce), no asynchronous start/done halves, none inside a fusion.
+    A trace's operations of those kinds then cover the collectives'
+    device time, which is what ``exchange_ms`` reads."""
+    probe, emit, _ = _mesh_programs(topo, form, max_pairs)
+    for lowered in (probe, emit):
         text = lowered.compile().as_text()
         kinds, fused = set(), set()
         computation = ""
@@ -176,3 +185,22 @@ def test_mesh_collectives_compile_as_synchronous_ops(topo, form, max_pairs):
                     fused.add(found.group(0))
         assert kinds and kinds <= {"all-reduce", "all-to-all"}, kinds
         assert not fused, fused
+
+
+RANKS_OP = re.compile(r"= s32\[(\d+)\]\S* (gather|scatter)\(.*"
+                      r'op_name="[^"]*/ddm\.ranks/')
+
+
+@pytest.mark.parametrize("form,max_pairs", MESH_FORMS)
+def test_mesh_emission_reads_each_opening_rank_with_one_gather(
+        topo, form, max_pairs):
+    """The mesh emission's ranks stage, compiled for a v5e:2x2, gathers
+    once per stream record (each emitter's opening rank from the join
+    table) and builds its two tables with at most three scatters: no
+    per-extent table is gathered back at the records that wrote it."""
+    _, emit, shard = _mesh_programs(topo, form, max_pairs, n=4096, m=3072)
+    ops = [(kind, int(size)) for size, kind in
+           RANKS_OP.findall(emit.compile().as_text())]
+    gathers = [size for kind, size in ops if kind == "gather"]
+    assert gathers == [shard], ops
+    assert 1 <= sum(kind == "scatter" for kind, _ in ops) <= 3, ops
