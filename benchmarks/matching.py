@@ -138,10 +138,9 @@ def enumeration(rows: List[str]) -> None:
         k = int(sbm_count(subs, upds, num_segments=16))
         cap = round_up_pow2(k)
         dt_count = _time(lambda: sbm_count(subs, upds, num_segments=16))
-        pairs, cnt = sbm_enumerate(subs, upds, max_pairs=cap, num_segments=16)
+        pairs, cnt = sbm_enumerate(subs, upds, max_pairs=cap)
         assert int(cnt) == k, (tag, int(cnt), k)
-        dt_sweep = _time(lambda: sbm_enumerate(subs, upds, max_pairs=cap,
-                                               num_segments=16))
+        dt_sweep = _time(lambda: sbm_enumerate(subs, upds, max_pairs=cap))
         rows.append(f"enum_count_{tag},{dt_count*1e6:.1f},K={k}")
         rows.append(f"enum_sweep_{tag},{dt_sweep*1e6:.1f},K={k}")
         if include_blocked:
@@ -230,7 +229,7 @@ def smoke(rows: List[str]) -> None:
     assert int(bf_count(subs, upds, block=256)) == k
     assert sequential_sbm_count_numpy(subs, upds) == k
     cap = round_up_pow2(k)
-    pairs, cnt = sbm_enumerate(subs, upds, max_pairs=cap, num_segments=8)
+    pairs, cnt = sbm_enumerate(subs, upds, max_pairs=cap)
     assert int(cnt) == k
     _, cnt_b = enumerate_matches(subs, upds, max_pairs=cap, block=256)
     assert int(cnt_b) == k
@@ -240,8 +239,7 @@ def smoke(rows: List[str]) -> None:
     # runtime, not first-call tracing, with the min-of-N estimator
     # (_time_min) that shrugs off runner contention spikes
     dt_count = _time_min(lambda: sbm_count(subs, upds, num_segments=8))
-    dt_enum = _time_min(lambda: sbm_enumerate(subs, upds, max_pairs=cap,
-                                              num_segments=8))
+    dt_enum = _time_min(lambda: sbm_enumerate(subs, upds, max_pairs=cap))
     rows.append(f"matching_smoke_count_n{n},{dt_count*1e6:.1f},")
     rows.append(f"matching_smoke_enum_n{n},{dt_enum*1e6:.1f},")
 
@@ -293,8 +291,8 @@ def smoke(rows: List[str]) -> None:
             f"probe_us={ph.get('probe', 0.0)*1e6:.1f};"
             f"emit_us={ph.get('emit', 0.0)*1e6:.1f}")
 
-    _, c1, _ = sbm_enumerate_planned(subs, upds, num_segments=8)   # warmup
-    _, c2, st = sbm_enumerate_planned(subs, upds, num_segments=8)
+    _, c1, _ = sbm_enumerate_planned(subs, upds)   # warmup
+    _, c2, st = sbm_enumerate_planned(subs, upds)
     assert int(c1) == int(c2) == k
     assert st.retries == 0, f"retry on identical rerun: {st.as_dict()}"
     assert st.recompiles == 0, f"recompile after warmup: {st.as_dict()}"

@@ -174,8 +174,7 @@ def enumerate_matches_ddim(
         return bitmatrix_enumerate(subs, upds, max_pairs=max_pairs)
     if subs.ndim_space == 1:   # before the probe — 1-d needs no selection
         if method == "sweep":
-            return sbm_enumerate(subs, upds, max_pairs=max_pairs,
-                                 num_segments=num_segments)
+            return sbm_enumerate(subs, upds, max_pairs=max_pairs)
         return enumerate_matches(subs, upds, max_pairs=max_pairs, block=block)
     if method == "sweep":
         if generator_dim is None:
@@ -185,8 +184,7 @@ def enumerate_matches_ddim(
             gen = generator_dim
 
         def candidates(a: Extents, b: Extents):
-            return sbm_enumerate(a, b, max_pairs=max_pairs,
-                                 num_segments=num_segments)
+            return sbm_enumerate(a, b, max_pairs=max_pairs)
     else:  # blocked
         gen = 0 if generator_dim is None else generator_dim
 

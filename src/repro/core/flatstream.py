@@ -27,9 +27,10 @@ import numpy as np
 class _Prep:
     """Position-space rank tables of one frozen index state.
 
-    The same quantities as :func:`repro.core.sweep.rank_tables_from_cumsums`
-    (a/b per-extent rank ranges + rank→id maps), built from the persistent
-    sorted stream — by two whole-stream cumsums here, or assembled from
+    DESIGN.md §3's position-space rank ranges (a/b per-extent rank ranges
+    + rank→id maps; the device sweep keeps their opening ranks in its join
+    table, :func:`repro.core.enumerate._join_tables`), built from the
+    persistent sorted stream — by two whole-stream cumsums here, or assembled from
     per-block cached tables by the blocked backend — and cached until the
     next mutation.
     """
@@ -142,8 +143,9 @@ class FlatEndpointStream:
         """Whole-stream cumsum rank tables (DESIGN.md §6).
 
         An inclusive cumsum read at a foreign-type position counts the
-        strictly-before lowers — exactly ``rank_tables_from_cumsums``'
-        scatter, done once per batch on the host stream.
+        strictly-before lowers — exactly what the device sweep's join
+        table records (``_join_tables``), done once per batch on the host
+        stream.
         """
         is_upper, is_sub, owner = self.is_upper, self.is_sub, self.owner
         sel_lo = ~is_upper

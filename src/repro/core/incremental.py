@@ -33,9 +33,9 @@ measures the bulk axis); the service's cache-drop fallback
 (``DDMService.invalidate_cache()`` → one stateless sweep rebuild)
 remains available when most of the world changes.
 
-Rematching reuses the rank-table construction of
-:func:`repro.core.sweep.rank_tables_from_cumsums` *restricted to changed
-extents* (DESIGN.md §6): in the sorted stream every endpoint has a unique
+Rematching reuses the position-space rank ranges of the sweep's emission
+(DESIGN.md §3; :func:`repro.core.enumerate._join_tables`) *restricted to
+changed extents* (DESIGN.md §6): in the sorted stream every endpoint has a unique
 position, so each region's match set splits into
 
 * **class A** (counterpart opens later) — a *contiguous rank range* over

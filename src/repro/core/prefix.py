@@ -62,26 +62,6 @@ def cumsum_blelloch(x: jax.Array) -> jax.Array:
     return lax.associative_scan(jnp.add, x, axis=-1)
 
 
-def cumsum_saturating_i32(x: jax.Array, axis: int = -1) -> jax.Array:
-    """Inclusive cumsum of *nonnegative* int32 that saturates at 2³¹−1.
-
-    ``jnp.cumsum`` on int32 wraps silently once the running total reaches
-    2³¹ — for pair-enumeration offset tables that corrupts the binary search
-    (the array stops being monotonic) and the returned count.  Saturating
-    addition of nonnegatives is associative (both groupings equal
-    ``min(Σ, 2³¹−1)``), so a tree scan is legal; a single wrap of two
-    operands below 2³¹ always lands in the negative range, which is the
-    overflow detector.  The result is exact below 2³¹ and pinned at 2³¹−1
-    (a documented sentinel, never a wrapped value) above.
-    """
-
-    def sat_add(a, b):
-        s = a + b
-        return jnp.where(s < 0, jnp.int32((1 << 31) - 1), s)
-
-    return lax.associative_scan(sat_add, x.astype(jnp.int32), axis=axis)
-
-
 # --------------------------------------------------------------------------
 # Distributed scan (the two-level scheme across a mesh axis)
 # --------------------------------------------------------------------------

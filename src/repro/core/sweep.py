@@ -268,78 +268,6 @@ def sbm_active_profile(subs: Extents, upds: Extents, *, num_segments: int = 8):
 
 
 # --------------------------------------------------------------------------
-# Emission ranks — the offset side of sweep-based pair *enumeration*
-# --------------------------------------------------------------------------
-
-def rank_tables_from_cumsums(is_sub, is_upper, owner, c_sub_lo, c_upd_lo,
-                             n: int, m: int):
-    """Per-extent emission ranges from the two lower-indicator cumsums.
-
-    Position-space form of the emission phase (DESIGN.md §3).  In the sorted
-    stream every endpoint has a unique position, so "pair (i, j) overlaps" is
-    exactly "the later of the two lower endpoints falls strictly inside the
-    other extent's position interval".  Partitioning pairs by which extent
-    opens later makes each extent's emission set a *contiguous rank range*
-    over the counterpart type's lower endpoints:
-
-      class A (upd opens later):  j ∈ upds_by_lo[a_start[i] : a_start[i]+a_count[i]]
-      class B (sub opens later):  i ∈ subs_by_lo[b_start[j] : b_start[j]+b_count[j]]
-
-    where ``a_start[i]``/``a_count[i]`` are the counterpart-lower cumsum
-    evaluated at S_i's two endpoint positions (and symmetrically for B), and
-    ``*_by_lo`` maps a lower-endpoint rank back to the owning extent id.
-    Each overlapping pair lands in exactly one class, so
-    ``sum(a_count) + sum(b_count) = K``, matching :func:`_emission_counts`.
-
-    ``is_sub``/``is_upper``: bool, ``owner``: int32 (>= 0 real, < 0 pad),
-    ``c_*_lo``: int32 *global* inclusive cumsums — all aligned with the
-    (possibly sharded) stream slice this caller holds.  Every output is
-    linear in the entries scattered from the slice, so a shard holding a
-    contiguous slice psums its tables with the other shards' into the
-    whole ones.
-    """
-    real = owner >= 0   # padding records never contribute a table entry
-
-    def scatter(count, sel, vals):
-        idx = jnp.where(sel, owner, count)
-        return jnp.zeros((count,), jnp.int32).at[idx].set(
-            jnp.where(sel, vals, 0), mode="drop")
-
-    sel_s_lo = is_sub & ~is_upper & real
-    sel_s_up = is_sub & is_upper & real
-    sel_u_lo = ~is_sub & ~is_upper & real
-    sel_u_up = ~is_sub & is_upper & real
-
-    a_start = scatter(n, sel_s_lo, c_upd_lo)   # upd lowers before S_i opens
-    a_end = scatter(n, sel_s_up, c_upd_lo)     # upd lowers before S_i closes
-    b_start = scatter(m, sel_u_lo, c_sub_lo)
-    b_end = scatter(m, sel_u_up, c_sub_lo)
-
-    # rank → extent id (c_*_lo - 1 is this lower endpoint's 0-based rank)
-    subs_by_lo = jnp.zeros((n,), jnp.int32).at[
-        jnp.where(sel_s_lo, c_sub_lo - 1, n)].set(
-        jnp.where(sel_s_lo, owner, 0), mode="drop")
-    upds_by_lo = jnp.zeros((m,), jnp.int32).at[
-        jnp.where(sel_u_lo, c_upd_lo - 1, m)].set(
-        jnp.where(sel_u_lo, owner, 0), mode="drop")
-    return a_start, a_end - a_start, b_start, b_end - b_start, \
-        subs_by_lo, upds_by_lo
-
-
-def emission_rank_tables(ep: EndpointStream, n: int, m: int, cumsum_fn):
-    """:func:`rank_tables_from_cumsums` over a whole sorted stream.
-
-    Computes the two lower-indicator cumsums with the supplied scan backend
-    (the same four-cumsum machinery as the counting sweep) and builds the
-    per-extent tables.  Requires well-formed extents (lo <= hi).
-    """
-    sub_lo, _sub_up, upd_lo, _upd_up = _indicator_deltas(ep)
-    return rank_tables_from_cumsums(
-        ep.is_sub, ep.is_upper, ep.owner,
-        cumsum_fn(sub_lo), cumsum_fn(upd_lo), n, m)
-
-
-# --------------------------------------------------------------------------
 # Faithful set-form (Algorithm 5 + 6): delta sets + monoid prefix
 # --------------------------------------------------------------------------
 

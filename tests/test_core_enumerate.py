@@ -1,6 +1,8 @@
 """Sweep-based pair enumeration: every engine (XLA sweep, Pallas pass C,
 blocked oracle, d-dim composition) returns exactly the brute-force pair set,
 including ties, duplicates, zero-length intervals and the overflow contract."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,14 +17,14 @@ from repro.core import (
     make_clustered_workload,
     make_uniform_workload,
     sbm_enumerate,
+    sbm_enumerate_planned,
 )
 from repro.core import enumerate as enumerate_mod
-from repro.core.enumerate import (_emit_pairs, _expand_slots, _offset_cumsum,
-                                  _sbm_enumerate_jit, _search_slots,
+from repro.core.enumerate import (_emit_sharded, _expand_slots_sharded,
+                                  _one_device_mesh, _pinned_offsets,
+                                  _search_slots,
                                   enumerate_matches_sweep_numpy)
-from repro.core.sweep import (_pad_stream, emission_rank_tables,
-                              encode_endpoints, resolve_cumsum,
-                              sequential_sbm_pairs_numpy)
+from repro.core.sweep import _sort_count_sharded, sequential_sbm_pairs_numpy
 from repro.kernels import sbm_enumerate_kernel
 
 jax.config.update("jax_platform_name", "cpu")
@@ -44,11 +46,12 @@ def _check_all_engines(subs, upds):
     want = brute_force_pairs_numpy(subs, upds)
     cap = max(len(want), 1) + 8
     assert sequential_sbm_pairs_numpy(subs, upds) == want
-    for scan_impl in ("two_level", "xla"):
-        pairs, count = sbm_enumerate(subs, upds, max_pairs=cap,
-                                     num_segments=4, scan_impl=scan_impl)
-        assert int(count) == len(want)
-        assert _pset(pairs) == want
+    pairs, count = sbm_enumerate(subs, upds, max_pairs=cap)
+    assert int(count) == len(want)
+    assert _pset(pairs) == want
+    pairs, count, _ = sbm_enumerate_planned(subs, upds)
+    assert int(count) == len(want)
+    assert _pset(pairs) == want
     pairs, count = sbm_enumerate_kernel(subs, upds, max_pairs=cap,
                                         block_size=32, interpret=True)
     assert int(count) == len(want)
@@ -120,6 +123,20 @@ def test_overflow_still_counts(engine):
     assert got <= want               # ...with genuine pairs only
 
 
+def test_planned_call_without_a_mesh_is_the_one_device_mesh_call():
+    """A planned call given no mesh runs the mesh programs on one device:
+    the buffer and count are those of a call on a one-device mesh."""
+    subs, upds = make_uniform_workload(jax.random.PRNGKey(5), 300, 400,
+                                       alpha=2.0, length=1000.0)
+    pairs, count, stats = sbm_enumerate_planned(subs, upds)
+    mesh_pairs, mesh_count, mesh_stats = sbm_enumerate_planned(
+        subs, upds, mesh=jax.make_mesh((1,), ("q",)))
+    np.testing.assert_array_equal(pairs, mesh_pairs)
+    assert int(count) == int(mesh_count) == stats.count > 0
+    assert stats.chips == mesh_stats.chips == 1
+    assert stats.regime == mesh_stats.regime
+
+
 # ---------------------------------------------------------------------------
 # randomized agreement (uniform, clustered, integer-grid ties)
 # ---------------------------------------------------------------------------
@@ -161,8 +178,7 @@ def test_sweep_matches_blocked_on_larger_instance():
     subs, upds = make_uniform_workload(jax.random.PRNGKey(3), 800, 700,
                                        alpha=10.0, length=1.0e5)
     want = brute_force_pairs_numpy(subs, upds)
-    pairs, count = sbm_enumerate(subs, upds, max_pairs=len(want) + 1,
-                                 num_segments=16)
+    pairs, count = sbm_enumerate(subs, upds, max_pairs=len(want) + 1)
     assert int(count) == len(want)
     assert _pset(pairs) == want
 
@@ -219,8 +235,7 @@ if HAVE_HYPOTHESIS:
         subs, upds = _mk(*data)
         want = brute_force_pairs_numpy(subs, upds)
         cap = max(len(want), 1) + 4
-        pairs, count = sbm_enumerate(subs, upds, max_pairs=cap,
-                                     num_segments=4)
+        pairs, count = sbm_enumerate(subs, upds, max_pairs=cap)
         assert int(count) == len(want)
         assert _pset(pairs) == want
 
@@ -229,45 +244,48 @@ if HAVE_HYPOTHESIS:
 # slot maps: binary search against expansion by scatter and prefix scan
 # ---------------------------------------------------------------------------
 
-def _synthetic_tables(a_cnt, b_cnt, seed=0):
-    """Offset and rank tables with the given per-emitter counts: each
-    emitter's range start drawn so its counterpart ranks stay inside the
-    table, the rank→id tables random permutations."""
-    n, m = len(a_cnt), len(b_cnt)
-    rng = np.random.RandomState(seed)
-    a_start = jnp.asarray([rng.randint(0, m - c + 1) for c in a_cnt],
-                          jnp.int32)
-    b_start = jnp.asarray([rng.randint(0, n - c + 1) for c in b_cnt],
-                          jnp.int32)
-    counts = jnp.asarray(list(a_cnt) + list(b_cnt), jnp.int32)
-    off = _offset_cumsum(counts)
-    return (off, counts, off[-1], a_start, b_start,
-            jnp.asarray(rng.permutation(n), jnp.int32),
-            jnp.asarray(rng.permutation(m), jnp.int32))
+@functools.partial(jax.jit, static_argnames=("max_pairs",))
+def _slot_maps(counts, starts, *, max_pairs):
+    """Both slot maps of emitters with these counts and counterpart
+    starts, on their pinned offsets: the search's emitter and rank, and
+    :func:`_expand_slots_sharded`'s emitter and base inside a one-device
+    ``shard_map``."""
+    from jax.sharding import PartitionSpec as P
+
+    excl, total = _pinned_offsets(counts, max_pairs)
+    e_search, r = _search_slots(jnp.arange(max_pairs, dtype=jnp.int32), excl)
+    expand = jax.shard_map(
+        lambda x, *v: tuple(_expand_slots_sharded(x, v, max_pairs, "p")),
+        mesh=_one_device_mesh(), in_specs=P("p"), out_specs=P("p"),
+        check_vma=False)
+    e_expand, base = expand(excl, jnp.arange(counts.shape[0]), starts - excl)
+    return total, e_search, r, e_expand, base
 
 
-def _workload_tables(subs, upds):
-    """The program's own offset and rank tables of an input."""
-    n, m = subs.lo.shape[0], upds.lo.shape[0]
-    ep = _pad_stream(encode_endpoints(subs, upds), 8)
-    a_start, a_cnt, b_start, b_cnt, subs_by_lo, upds_by_lo = \
-        emission_rank_tables(ep, n, m, resolve_cumsum("two_level", 8))
-    counts = jnp.concatenate([a_cnt, b_cnt])
-    off = _offset_cumsum(counts)
-    return off, counts, off[-1], a_start, b_start, subs_by_lo, upds_by_lo
+def _emit_forced(subs, upds, max_pairs, form, monkeypatch):
+    """The emission program from the probe's stream with ``form`` as its
+    slot map, traced afresh."""
+    n, m = subs.size, upds.size
+    tags, _ = _sort_count_sharded(subs, upds, mesh=_one_device_mesh(),
+                                  axis_name="p")
+    with monkeypatch.context() as patch:
+        patch.setattr(enumerate_mod, "_slot_map", lambda *_: form)
+        return jax.jit(lambda t: _emit_sharded.__wrapped__(
+            t, n=n, m=m, max_pairs=max_pairs, mesh=_one_device_mesh(),
+            axis_name="p"))(tags)
 
 
 SLOT_MAP_CASES = {
-    # name: (a_cnt, b_cnt, max_pairs) or (workload, max_pairs)
-    "zero_runs_lead_inner_trail": ([0, 0, 3, 0, 0, 2, 0],
-                                   [0, 1, 0, 0, 4, 0, 0], 16),
-    "k_zero": ([0, 0, 0], [0, 0], 8),
-    "k_equals_max_pairs": ([2, 0, 3, 1], [0, 2, 0, 0], 8),
-    "k_beyond_max_pairs": ([3, 4, 0, 2], [4, 0, 1, 3], 6),
-    "one_emitter_owns_every_slot": ([0, 0, 5, 0], [0, 0, 0, 0, 0], 5),
-    "one_emitter_overflows": ([0, 0, 5, 0], [0, 0, 0, 0, 0], 3),
-    "n_is_one": ([3], [0, 1, 0], 4),
-    "m_is_one": ([1, 0, 1, 1], [3], 8),
+    # name: (emitter counts, max_pairs) or (workload, max_pairs)
+    "zero_runs_lead_inner_trail": ([0, 0, 3, 0, 0, 2, 0,
+                                    0, 1, 0, 0, 4, 0, 0], 16),
+    "k_zero": ([0, 0, 0, 0, 0], 8),
+    "k_equals_max_pairs": ([2, 0, 3, 1, 0, 2, 0, 0], 8),
+    "k_beyond_max_pairs": ([3, 4, 0, 2, 4, 0, 1, 3], 6),
+    "one_emitter_owns_every_slot": ([0, 0, 5, 0, 0, 0, 0, 0, 0], 5),
+    "one_emitter_overflows": ([0, 0, 5, 0, 0, 0, 0, 0, 0], 3),
+    "n_is_one": ([3, 0, 1, 0], 4),
+    "m_is_one": ([1, 0, 1, 1, 3], 8),
     "ties": (lambda: _mk([0, 2, 2, 4, 4], [2, 4, 4, 6, 6],
                          [2, 2, 4, 0], [2, 4, 4, 6]), 32),
     "duplicates": (lambda: _mk([1.0] * 9, [2.0] * 9, [1.5] * 7, [3.0] * 7),
@@ -279,51 +297,54 @@ SLOT_MAP_CASES = {
 
 @pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
 @pytest.mark.parametrize("case", sorted(SLOT_MAP_CASES))
-def test_slot_maps_are_bit_identical(case, x64):
+def test_slot_maps_are_bit_identical(case, x64, monkeypatch):
     """The expansion gives every slot the search's emitter and counterpart
-    rank, and the same padded buffer, on the same offset tables."""
-    spec = SLOT_MAP_CASES[case]
+    rank on the same pinned offsets; on a workload's stream the emission
+    returns the same padded buffer with either slot map forced."""
+    spec, max_pairs = SLOT_MAP_CASES[case]
     with jax.enable_x64(x64):
-        if callable(spec[0]):
-            tables = _workload_tables(*spec[0]())
-        else:
-            tables = _synthetic_tables(*spec[:2])
-        max_pairs = spec[-1]
-        off, counts, k_total, a_start, b_start = tables[:5]
-        n, m = a_start.shape[0], b_start.shape[0]
-        slots = jnp.arange(max_pairs, dtype=jnp.int32)
-
-        e_search, r = _search_slots(slots, off, counts)
-        starts = jnp.concatenate([a_start, m + b_start])
-        e_expand, base = _expand_slots(off - counts, starts - (off - counts),
-                                       max_pairs, 8)
+        if callable(spec):
+            subs, upds = spec()
+            want = brute_force_pairs_numpy(subs, upds)
+            search, expand = (_emit_forced(subs, upds, max_pairs, f,
+                                           monkeypatch)
+                              for f in ("search", "expand"))
+            assert search[0].dtype == expand[0].dtype == jnp.int32
+            np.testing.assert_array_equal(expand[0], search[0])
+            assert int(search[1]) == int(expand[1]) == len(want)
+            k = min(len(want), max_pairs)
+            got = np.asarray(search[0])
+            assert np.all(got[k:] == -1) and np.all(got[:k] >= 0)
+            assert _pset(got) <= want and len(_pset(got)) == k
+            return
+        counts = np.asarray(spec, np.int32)
+        starts = np.random.RandomState(len(spec)).randint(
+            0, 1000, len(spec)).astype(np.int32)
+        total, e_search, r, e_expand, base = (np.asarray(x) for x in (
+            _slot_maps(counts, starts, max_pairs=max_pairs)))
+        assert int(total) == min(sum(spec), max_pairs)
         np.testing.assert_array_equal(e_expand, e_search)
-        np.testing.assert_array_equal(base + slots, starts[e_search] + r)
-
-        search, expand = (_emit_pairs(*tables, max_pairs=max_pairs,
-                                      num_segments=8, form=f)
-                          for f in ("search", "expand"))
-        assert search.dtype == expand.dtype == jnp.int32
-        np.testing.assert_array_equal(expand, search)
-        k = min(int(k_total), max_pairs)
-        got = np.asarray(search)
-        assert np.all(got[k:] == -1) and np.all(got[:k] >= 0)
-        assert np.all(got[:k, 0] < n) and np.all(got[:k, 1] < m)
+        np.testing.assert_array_equal(base + np.arange(max_pairs),
+                                      starts[e_search] + r)
+        # every slot below K lies in its emitter's range
+        k = int(total)
+        assert np.all(r[:k] < counts[e_search][:k])
 
 
 @pytest.mark.parametrize("max_pairs,form", [(64, "search"), (1024, "expand")])
 def test_enumerate_rows_equal_the_search_rows(monkeypatch, max_pairs, form):
-    """The program takes the rule's slot map and returns, row for row, what
-    it returns with the search forced."""
+    """The emission takes the rule's slot map for the stream's 2·(n+m)
+    records and returns, row for row, what it returns with the search
+    forced."""
     subs, upds = make_uniform_workload(jax.random.PRNGKey(11), 300, 400,
                                        alpha=2.0, length=1000.0)
-    assert enumerate_mod._slot_map(max_pairs, 700) == form
-    static = dict(max_pairs=max_pairs, num_segments=8, scan_impl="two_level")
-    pairs, count = _sbm_enumerate_jit(subs, upds, **static)
-    monkeypatch.setattr(enumerate_mod, "_slot_map", lambda *_: "search")
-    searched = jax.jit(_sbm_enumerate_jit.__wrapped__,
-                       static_argnames=tuple(static))
-    want_pairs, want_count = searched(subs, upds, **static)
+    assert enumerate_mod._slot_map(max_pairs, 1400) == form
+    tags, _ = _sort_count_sharded(subs, upds, mesh=_one_device_mesh(),
+                                  axis_name="p")
+    pairs, count = _emit_sharded(tags, n=300, m=400, max_pairs=max_pairs,
+                                 mesh=_one_device_mesh(), axis_name="p")
+    want_pairs, want_count = _emit_forced(subs, upds, max_pairs, "search",
+                                          monkeypatch)
     assert int(count) == int(want_count) > max_pairs // 2
     np.testing.assert_array_equal(pairs, want_pairs)
 

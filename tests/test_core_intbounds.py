@@ -58,8 +58,7 @@ def test_int32_sets_match_algorithm_4(case):
     assert int(sbm_count(subs, upds)) == len(want)
     assert sbm_count_exact(subs, upds) == len(want)
     for cap in (len(want) + 3, 2 * len(want) + 64):
-        pairs, count = sbm_enumerate(subs, upds, max_pairs=cap,
-                                     num_segments=8)
+        pairs, count = sbm_enumerate(subs, upds, max_pairs=cap)
         got = {(int(i), int(j)) for i, j in np.asarray(pairs) if i >= 0}
         assert int(count) == len(want) and got == want
     pairs, count, stats = sbm_enumerate_planned(subs, upds)

@@ -124,17 +124,18 @@ _SCRIPT = textwrap.dedent("""
 
     # K = 2^32 with every pair emitted inside one shard: identical
     # extents put the 2^16 subscription uppers, each emitting 2^16 pairs,
-    # in one shard's range.  That shard's total saturates while the
-    # others emit nothing, so the lane sums stay below the sentinel; the
-    # buffer must still blank, on either slot map.
+    # in one shard's range.  That shard's offsets run past 2^31 while the
+    # others emit nothing; on either slot map the count pins at the
+    # sentinel and every row is a genuine pair, each once.
     same = ext(np.full(2**16, 9), np.full(2**16, 12), "int32")
     for form, cap in (("search", 16), ("expand", 2**15)):
         pairs, count = sbm_enumerate_sharded(same, same, mesh, "p",
                                              max_pairs=cap)
         a = np.asarray(pairs)
         out["saturated_" + form] = {
-            "count": int(count), "blank": bool((a == -1).all()),
-            "rows": int(a.shape[0])}
+            "count": int(count), "rows": int(a.shape[0]),
+            "genuine": bool(((a >= 0) & (a < 2**16)).all()),
+            "distinct": len({(int(i), int(j)) for i, j in a})}
     # Each emitter on the mesh reads its opening rank from the join table
     # that its extent's lower record wrote, on whatever shard that lies.
     # Every case runs both slot maps, at a buffer of K (search) and of
@@ -242,9 +243,12 @@ def test_emission_on_a_mesh_retries_past_the_first_bucket(results):
 @pytest.mark.parametrize("form,rows", [("search", 16), ("expand", 2**15)])
 def test_emission_on_a_mesh_blanks_when_one_shard_saturates(results, form,
                                                             rows):
+    """K = 2³² inside one shard, without x64: the count pins at 2³¹−1 and
+    the buffer is filled with genuine pairs, not blanked (the name is the
+    contract this test once held)."""
     r = results["saturated_" + form]
     assert r["count"] == 2**31 - 1 and r["rows"] == rows
-    assert r["blank"]
+    assert r["genuine"] and r["distinct"] == rows
 
 
 JOIN_CASES = ["cross_subs_i32", "cross_upds_f32", "upper_only_shard_i32",
@@ -283,14 +287,13 @@ def test_emission_exchange_bytes_count_its_collectives(form, max_pairs, x64):
     from repro.core.enumerate import _emit_exchange_bytes
 
     n, m, p = 1003, 420, 4
-    c = 8 if x64 else 4                      # a pair count's bytes
+    c = 8 if x64 else 4                      # a count lane's bytes
     slots = p * -(-max_pairs // p)
     all_reduce, all_gather, one_way = 2 * (p - 1), p * (p - 1), p - 1
     want = (2 * all_gather * 4               # the lower cumsums' carries
             + 2 * all_reduce * 4 * (n + m)   # open_at and table psums
-            + all_gather * c                 # the shards' offsets
-            # the count: one int64 psum, or two psums of 15-bit lanes
-            + (1 if x64 else 2) * all_reduce * c)
+            + all_gather * 4                 # the shards' pinned totals
+            + 4 * all_reduce * c)            # the count's lane partials
     if form == "expand":
         # per slot value (emitter code, base): the last record's value,
         # the marks' reduce-scatter, the slot scan's carry

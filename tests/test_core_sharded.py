@@ -82,7 +82,7 @@ _SCRIPT = textwrap.dedent("""
     assert int(cnt2) == len(bf_pairs(subs2, upds2)), int(cnt2)
 
     # K >= 2^31 across shards (duplicated extents): without x64 the count
-    # must pin at the sentinel and the buffer must blank, never mis-stitch
+    # must pin at the sentinel and the buffer hold genuine pairs, each once
     n = m = 1 << 16
     big_s = Extents(jnp.zeros(n, jnp.float32), jnp.ones(n, jnp.float32))
     big_u = Extents(jnp.full(m, 0.5, jnp.float32), jnp.full(m, 2.0, jnp.float32))
@@ -94,7 +94,9 @@ _SCRIPT = textwrap.dedent("""
         assert big_k == n * m
     else:
         assert int(cnt_o) == 2**31 - 1, int(cnt_o)
-        assert np.all(np.asarray(pairs_o) == -1)
+        rows = np.asarray(pairs_o)
+        assert np.all((rows >= 0) & (rows < n))
+        assert len({(int(i), int(j)) for i, j in rows}) == 16
         assert big_k == 2**31 - 1, big_k    # saturates, never wraps
     print("SHARDED_OK", want)
 """)

@@ -204,16 +204,29 @@ def test_enumerate_offsets_beyond_int32():
     assert np.all(arr >= 0) and np.all(arr[:, 0] < n) and np.all(arr[:, 1] < m)
 
 
-def test_saturating_cumsum_contract():
-    from repro.core.prefix import cumsum_saturating_i32
-    x = jnp.asarray([1, 2, 3, 4], jnp.int32)
-    np.testing.assert_array_equal(np.asarray(cumsum_saturating_i32(x)),
-                                  [1, 3, 6, 10])          # exact below 2³¹
-    big = jnp.full((5,), 2**30, jnp.int32)
-    got = np.asarray(cumsum_saturating_i32(big))
-    assert got[0] == 2**30 and got[1] == 2**31 - 1        # saturated
-    assert np.all(np.diff(got) >= 0), "must stay monotonic past saturation"
-    assert got[-1] == 2**31 - 1
+OFFSET_CASES = {
+    # name: (counts, cap)
+    "below_cap": ([3, 0, 2, 4], 16),
+    "crossing_lands_on_cap": ([3, 5, 2, 4], 8),
+    "one_count_past_2e30": ([5, 2**30 + 7, 1, 2**31 - 1, 3], 2**20),
+    "k_is_2e32": ([2**16] * 2**16, 2**29),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OFFSET_CASES))
+def test_emission_offsets_pin_at_the_buffer(case):
+    """The emission's offsets are exactly min(exclusive prefix, cap) and
+    its total min(K, cap), from one int32 scan, however far K runs past
+    2³¹: monotonic, so every slot below the cap finds its emitter."""
+    from repro.core.enumerate import _pinned_offsets
+
+    counts, cap = OFFSET_CASES[case]
+    c = np.asarray(counts, np.int64)
+    want = np.minimum(np.cumsum(c) - c, cap)
+    excl, total = jax.jit(_pinned_offsets, static_argnums=1)(
+        jnp.asarray(counts, jnp.int32), cap)
+    np.testing.assert_array_equal(np.asarray(excl), want)
+    assert int(total) == min(int(c.sum()), cap)
 
 
 def test_algorithm6_active_sets_match_sequential():

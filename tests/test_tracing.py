@@ -4,7 +4,7 @@
 host span ``ddm.<phase>``; ``readback`` wraps a blocking device→host read
 in ``ddm.<phase>.readback`` and counts it.  Inside the two device
 programs of the planned sweep, ``jax.named_scope`` names the stages
-``ddm.sort``/``ddm.count`` (probe) and ``ddm.sort``/``ddm.ranks``/
+``ddm.sort``/``ddm.count`` (probe) and ``ddm.ranks``/``ddm.exchange``/
 ``ddm.search``/``ddm.gather`` (emission); the names are metadata only.
 """
 import contextlib
@@ -18,16 +18,15 @@ import jax
 import pytest
 
 from repro.core import make_uniform_workload, runtime
-from repro.core.enumerate import (_emit_sharded, _sbm_enumerate_jit,
+from repro.core.enumerate import (_emit_sharded, _one_device_mesh,
                                   _slot_map, sbm_enumerate_planned)
 from repro.core.sweep import _sbm_count_partials, _sort_count_sharded
 
 jax.config.update("jax_platform_name", "cpu")
 
 PROBE_SCOPES = {"ddm.sort", "ddm.count"}
-EMIT_SCOPES = {"ddm.sort", "ddm.ranks", "ddm.search", "ddm.gather"}
 MESH_EMIT_SCOPES = {"ddm.ranks", "ddm.exchange", "ddm.search", "ddm.gather"}
-MESH = jax.make_mesh((1,), ("p",))
+MESH = _one_device_mesh()
 
 
 def _workload(seed=0):
@@ -43,17 +42,13 @@ def _stream(subs, upds):
 
 
 # (name, jitted function, its arguments from the sets, static arguments,
-# stages) of the planned sweep's programs on one chip and on a mesh (one
-# device here; tests/test_core_mesh.py compiles them for four); with
-# n+m = 700 the emission expands 1024 slots and searches for 64
+# stages) of sbm_count's program and the planned sweep's two programs on
+# the one-device mesh a call without a mesh takes (tests/test_core_mesh.py
+# runs them on four); with 2·(n+m) = 1400 stream records the emission
+# expands 1024 slots and searches for 64
 PROGRAMS = [
     ("count", _sbm_count_partials, _sets,
      dict(num_segments=8, scan_impl="two_level"), PROBE_SCOPES),
-    ("emit", _sbm_enumerate_jit, _sets,
-     dict(max_pairs=1024, num_segments=8, scan_impl="two_level"),
-     EMIT_SCOPES),
-    ("emit_search", _sbm_enumerate_jit, _sets,
-     dict(max_pairs=64, num_segments=8, scan_impl="two_level"), EMIT_SCOPES),
     ("mesh_count", _sort_count_sharded, _sets,
      dict(mesh=MESH, axis_name="p"), PROBE_SCOPES),
     ("mesh_emit", _emit_sharded, _stream,
@@ -65,8 +60,7 @@ PROGRAMS = [
 ]
 # the loops each program keeps: only the binary search is a while (the
 # splitter search of the sort across the mesh needs two or more chips)
-LOOPS = {"count": {"sort"}, "emit": {"sort"}, "emit_search": {"sort", "while"},
-         "mesh_count": {"sort"}, "mesh_emit": set(),
+LOOPS = {"count": {"sort"}, "mesh_count": {"sort"}, "mesh_emit": set(),
          "mesh_emit_search": {"while"}}
 
 
@@ -180,7 +174,7 @@ def test_planned_call_names_its_slot_map(alpha, regime):
     """``stats.regime`` is the slot map the rule gives the planned buffer."""
     subs, upds = make_uniform_workload(jax.random.PRNGKey(4), 300, 400, alpha)
     _, _, stats = sbm_enumerate_planned(subs, upds)
-    assert stats.regime == _slot_map(stats.capacity, 700) == regime
+    assert stats.regime == _slot_map(stats.capacity, 1400) == regime
     assert stats.as_dict()["regime"] == regime
 
 
@@ -242,7 +236,7 @@ def test_every_stage_is_named_in_the_compiled_programs():
         loops = [(opc, op) for opc, op in insts if opc in ("sort", "while")]
         assert {opc for opc, _ in loops} == LOOPS[name], name
         assert all(_scope(op) for _, op in loops), (name, loops)
-    assert seen == PROBE_SCOPES | EMIT_SCOPES | MESH_EMIT_SCOPES
+    assert seen == PROBE_SCOPES | MESH_EMIT_SCOPES
 
 
 def test_named_scopes_are_metadata_only(monkeypatch):
@@ -268,18 +262,35 @@ def test_named_scopes_are_metadata_only(monkeypatch):
 
 # Optimized HLO, metadata stripped, of the one-chip programs at the static
 # cells' shapes (float32 bounds, n = m = 5·10⁵; 8,192 rows and 2²⁶), on
-# the CPU backend: the fingerprints of these programs before integer
-# bounds and the mesh path, which leave the float32 programs as they were.
+# the CPU backend: sbm_count's program, and the planned sweep's emission
+# from the probe's 2·10⁶ sorted records on the one-device mesh.
+def _bounds():
+    import jax.numpy as jnp
+
+    from repro.core import Extents
+
+    bounds = jax.ShapeDtypeStruct((500_000,), jnp.float32)
+    return Extents(bounds, bounds), Extents(bounds, bounds)
+
+
+def _tags():
+    import jax.numpy as jnp
+
+    return (jax.ShapeDtypeStruct((2_000_000,), jnp.int32),)
+
+
+def _emit_static(max_pairs):
+    return dict(n=500_000, m=500_000, max_pairs=max_pairs,
+                mesh=_one_device_mesh(), axis_name="p")
+
+
 FLOAT32_HLO = {
-    "count": (_sbm_count_partials, dict(num_segments=8,
-                                        scan_impl="two_level"),
+    "count": (_sbm_count_partials, _bounds,
+              dict(num_segments=8, scan_impl="two_level"),
               "67374da03d38841f"),
-    "emit": (_sbm_enumerate_jit, dict(max_pairs=8192, num_segments=8,
-                                      scan_impl="two_level"),
-             "02d6854ac9d107c3"),
-    "emit_2e26": (_sbm_enumerate_jit, dict(max_pairs=1 << 26, num_segments=8,
-                                           scan_impl="two_level"),
-                  "da45371356a35d33"),
+    "emit": (_emit_sharded, _tags, _emit_static(8192), "51e6454ca86003a1"),
+    "emit_2e26": (_emit_sharded, _tags, _emit_static(1 << 26),
+                  "9112ca80ea7ff295"),
 }
 
 
@@ -287,14 +298,8 @@ FLOAT32_HLO = {
 def test_float32_programs_keep_their_hlo(name):
     import hashlib
 
-    import jax.numpy as jnp
-
-    from repro.core import Extents
-
-    fn, static, want = FLOAT32_HLO[name]
-    bounds = jax.ShapeDtypeStruct((500_000,), jnp.float32)
+    fn, args, static, want = FLOAT32_HLO[name]
     with jax.enable_x64(False):
-        hlo = fn.lower(Extents(bounds, bounds), Extents(bounds, bounds),
-                       **static).compile().as_text()
+        hlo = fn.lower(*args(), **static).compile().as_text()
     got = hashlib.sha256(_strip_metadata(hlo).encode()).hexdigest()[:16]
     assert got == want
